@@ -74,7 +74,7 @@ def main():
 
     from repro.api import KernelKMeans
     from repro.data import blob_ring
-    from repro.serve import write_bench
+    from repro.serve import data_mesh, write_bench
     from repro.serve.bench import format_bench, run_benches
 
     key = jax.random.PRNGKey(args.seed)
@@ -92,7 +92,7 @@ def main():
         n_dev = len(jax.devices())
         if n_dev < 2:
             ap.error(f"--sharded needs >= 2 devices, have {n_dev}")
-        mesh = jax.make_mesh((n_dev,), ("data",))
+        mesh = data_mesh()
 
     modes = (("sync", "async", "fused", "swap", "backends")
              if args.mode == "all" else (args.mode,))

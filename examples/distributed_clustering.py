@@ -17,8 +17,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import polynomial_kernel, clustering_accuracy
 from repro.data import blob_ring
 from repro.distributed.cluster import distributed_one_pass_kernel_kmeans
+from repro.serve import data_mesh
 
-mesh = jax.make_mesh((jax.device_count(),), ("data",))
+mesh = data_mesh()
 n = 4096                                   # power of two (pre-padded)
 X, labels = blob_ring(jax.random.PRNGKey(0), n=n)
 X = jax.device_put(X, NamedSharding(mesh, P(None, "data")))

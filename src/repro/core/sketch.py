@@ -129,24 +129,24 @@ def srht_apply(srht: SRHT, V: jnp.ndarray,
     return out[:srht.n]
 
 
-def srht_rows(srht: SRHT, start: int, stop: int) -> jnp.ndarray:
-    """Materialize rows [start, stop) of the implicit Omega = D H R.
+def srht_rows_at(idx: jnp.ndarray, signs: jnp.ndarray, rows: jnp.ndarray,
+                 n_pad) -> jnp.ndarray:
+    """Materialize rows `idx` (int32, may be traced) of the implicit
+    Omega = D H R, given `signs` = the D entries of those rows.
 
     Omega[i, c] = signs[i] * (-1)^popcount(i & rows[c]) / sqrt(n_pad) —
     the Sylvester/Hadamard entry formula, i.e. exactly the value
     srht_apply_t would produce from the one-hot e_i column. O(b * r')
-    time and memory for a b-row slice, so the streaming accumulator
-    (repro.stream.accumulate) can apply the symmetric cross-term
+    time and memory for b rows, so the block updates of the streaming
+    accumulator (repro.stream.accumulate) and the sharded fit
+    (repro.distributed.fit) can apply the symmetric cross-term
     K_block @ Omega[rows] without a full FWHT over dead rows.
     """
-    if not (0 <= start <= stop <= srht.n):
-        raise ValueError(f"row slice [{start}, {stop}) outside [0, {srht.n})")
-    idx = jnp.arange(start, stop, dtype=jnp.int32)
-    bits = jnp.bitwise_and(idx[:, None], srht.rows.astype(jnp.int32)[None, :])
+    bits = jnp.bitwise_and(idx[:, None], rows.astype(jnp.int32)[None, :])
     parity = jax.lax.population_count(bits) & 1
-    scale = 1.0 / jnp.sqrt(jnp.asarray(srht.n_pad, jnp.float32))
+    scale = 1.0 / jnp.sqrt(jnp.asarray(n_pad, jnp.float32))
     vals = jnp.where(parity == 1, -scale, scale)
-    return srht.signs[start:stop, None] * vals
+    return signs[:, None] * vals
 
 
 class GaussianSketch(NamedTuple):

@@ -24,7 +24,8 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.sketch import next_pow2
@@ -75,11 +76,10 @@ def distributed_sketch(kernel, X, mesh, signs, rows, axis="data",
             inb = (rel >= 0) & (rel < n_local)
             rel_safe = jnp.clip(rel, 0, n_local - 1)
             contrib = jnp.where(inb[:, None], sl[rel_safe], 0.0)
-            return jax.lax.psum(contrib, axis)[None]   # (1, r', b)
-        out = shard_map(inner, mesh=mesh, in_specs=P(axis, None),
-                        out_specs=P(axis, None, None),
-                        check_rep=False)(stripe_f)
-        return out[0]                                    # (r', b)
+            return jax.lax.psum(contrib, axis)         # (r', b)
+        return shard_map(inner, mesh=mesh, in_specs=P(axis, None),
+                         out_specs=P(None, None),
+                         check_vma=False)(stripe_f)
 
     for start in range(0, n, block):
         b = min(block, n - start)
@@ -94,7 +94,7 @@ def distributed_sketch(kernel, X, mesh, signs, rows, axis="data",
         stripe = shard_map(mk_stripe, mesh=mesh,
                            in_specs=(P(None, axis), P(None, None)),
                            out_specs=P(axis, None),
-                           check_rep=False)(X, xb)       # (n, b) row-shard
+                           check_vma=False)(X, xb)       # (n, b) row-shard
         stripe = stripe * signs_sh[:, None]
         stripe = distributed_fwht(stripe, mesh, axis, normalize=False)
         wt_block = rt_gather(stripe) * scale             # (r', b)
@@ -111,13 +111,11 @@ def cholesky_qr(W, mesh, axis="data", eps: float = 1e-7):
     truncation is decided eagerly (this is orchestration code, not a jit
     body), so Q has static shape (n, rank) per pipeline run.
     """
-    import numpy as np
-
     def gram(wl):
-        return jax.lax.psum(wl.T @ wl, axis)[None]
+        return jax.lax.psum(wl.T @ wl, axis)
 
     G = shard_map(gram, mesh=mesh, in_specs=P(axis, None),
-                  out_specs=P(axis, None, None), check_rep=False)(W)[0]
+                  out_specs=P(None, None), check_vma=False)(W)
     evals, V = jnp.linalg.eigh(0.5 * (G + G.T))
     ev = np.asarray(evals)
     keep = ev > eps * max(float(ev.max()), 1e-30)
@@ -141,11 +139,11 @@ def distributed_omega_t(M, mesh, signs, rows, axis="data"):
         inb = (rel >= 0) & (rel < n_local)
         contrib = jnp.where(inb[:, None], sl[jnp.clip(rel, 0, n_local - 1)],
                             0.0)
-        return jax.lax.psum(contrib, axis)[None]
+        return jax.lax.psum(contrib, axis)
 
     out = shard_map(inner, mesh=mesh, in_specs=P(axis, None),
-                    out_specs=P(axis, None, None), check_rep=False)(Mh)
-    return out[0] * scale                  # (r', c)
+                    out_specs=P(None, None), check_vma=False)(Mh)
+    return out * scale                     # (r', c)
 
 
 def distributed_kmeans(Y, k, key, mesh, axis="data", n_iter: int = 20,
@@ -182,24 +180,27 @@ def distributed_kmeans(Y, k, key, mesh, axis="data", n_iter: int = 20,
 
             C, _ = jax.lax.scan(it, C, None, length=n_iter)
             C, labels, obj = step(C, yl)
-            return (labels.astype(jnp.int32), C[None],
-                    jnp.reshape(obj, (1,)))
+            return labels.astype(jnp.int32), C, obj
 
         return shard_map(
             body, mesh=mesh, in_specs=(P(None, axis), P(None, None)),
-            out_specs=(P(axis), P(axis, None, None), P(axis)),
-            check_rep=False)(Y, C0)
+            out_specs=(P(axis), P(None, None), P()),
+            check_vma=False)(Y, C0)
 
+    # Restart seeds are k columns of Y, picked on the host: indexing the
+    # sharded axis of Y with an index array has no unambiguous output
+    # sharding.
+    Y_host = np.asarray(Y)
     best = None
     for s in range(n_restarts):
         idx = jax.random.choice(jax.random.fold_in(key, s), n, (k,),
                                 replace=False)
-        C0 = jax.device_put(Y[:, idx].T,
+        C0 = jax.device_put(Y_host[:, np.asarray(idx)].T,
                             NamedSharding(mesh, P(None, None)))
         labels, C, obj = run_one(C0)
-        score = float(obj[0])
+        score = float(obj)
         if best is None or score < best[0]:
-            best = (score, labels, C[0])
+            best = (score, labels, C)
     return best[1], best[2], best[0]
 
 
@@ -220,9 +221,9 @@ def distributed_one_pass_kernel_kmeans(
     QtO = distributed_omega_t(Q, mesh, signs, rows, axis).T   # (r', r')
     # Q^T W: r' x r' via psum.
     def qtw(ql, wl):
-        return jax.lax.psum(ql.T @ wl, axis)[None]
+        return jax.lax.psum(ql.T @ wl, axis)
     QtW = shard_map(qtw, mesh=mesh, in_specs=(P(axis, None), P(axis, None)),
-                    out_specs=P(axis, None, None), check_rep=False)(Q, W)[0]
+                    out_specs=P(None, None), check_vma=False)(Q, W)
     Bt, *_ = jnp.linalg.lstsq(QtO.T, QtW.T)
     B = 0.5 * (Bt + Bt.T)
     evals, V = jnp.linalg.eigh(B)
@@ -235,7 +236,7 @@ def distributed_one_pass_kernel_kmeans(
         return proj @ ql.T                               # (r, n_local)
 
     Y = shard_map(embed, mesh=mesh, in_specs=P(axis, None),
-                  out_specs=P(None, axis), check_rep=False)(Q)
+                  out_specs=P(None, axis), check_vma=False)(Q)
     labels, C, obj = distributed_kmeans(Y, k, key, mesh, axis, n_iter)
     return DistClusterResult(labels=labels, Y=Y, centroids=C,
                              eigvals=evals[:r])

@@ -20,7 +20,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.sketch import fwht as _fwht_ref
@@ -72,4 +72,4 @@ def distributed_fwht(x: jnp.ndarray, mesh, axis: str = "data",
     spec = P(axis, *(None,) * (x.ndim - 1))
     # Every mesh axis other than `axis` sees replicated data.
     return shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
-                     check_rep=False)(x)
+                     check_vma=False)(x)

@@ -42,41 +42,12 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.kernels_fn import KernelFn
-from repro.core.sketch import SRHT, fwht
+from repro.core.sketch import SRHT, fwht, srht_rows_at
 from repro.distributed.dfwht import butterfly_stages
-
-
-def srht_rows_dynamic(sketch: SRHT, start, b: int) -> jnp.ndarray:
-    """Rows [start, start+b) of the implicit Omega with a TRACED start.
-
-    Same Sylvester entry formula as core.sketch.srht_rows (popcount is
-    exact integer arithmetic, so the values are identical); the static
-    variant can't be used inside the one-executable-per-block-width fit
-    path, where the block offset q is a traced scalar.
-    """
-    start = jnp.asarray(start, jnp.int32)
-    idx = start + jnp.arange(b, dtype=jnp.int32)
-    bits = jnp.bitwise_and(idx[:, None], sketch.rows.astype(jnp.int32)[None, :])
-    parity = jax.lax.population_count(bits) & 1
-    scale = 1.0 / jnp.sqrt(jnp.asarray(sketch.n_pad, jnp.float32))
-    vals = jnp.where(parity == 1, -scale, scale)
-    signs = jax.lax.dynamic_slice(sketch.signs, (start,), (b,))
-    return signs[:, None] * vals
-
-
-def _omega_rows_local(gids: jnp.ndarray, rows: jnp.ndarray, n_pad: int,
-                      signs_l: jnp.ndarray) -> jnp.ndarray:
-    """Materialize a device's own (L, r') slab of the implicit Omega —
-    the fused path's replacement for the distributed FWHT."""
-    bits = jnp.bitwise_and(gids[:, None], rows[None, :])
-    parity = jax.lax.population_count(bits) & 1
-    scale = 1.0 / jnp.sqrt(jnp.asarray(n_pad, jnp.float32))
-    vals = jnp.where(parity == 1, -scale, scale)
-    return signs_l[:, None] * vals
 
 
 class ShardedFitEngine:
@@ -230,7 +201,7 @@ class ShardedFitEngine:
                 kind, gamma, degree = statics
                 from repro.kernels.fit_sketch.ops import fit_sketch_pallas
                 if srht:
-                    O_l = _omega_rows_local(gids, rows_const, N, aux_l)
+                    O_l = srht_rows_at(gids, aux_l, rows_const, N)
                 else:
                     O_l = aux_l
                 O_l = jnp.where(valid[:, None], O_l, 0.0)
@@ -292,7 +263,10 @@ class ShardedFitEngine:
         def apply_fn(Xbuf, W, rn, aux, q):
             c = jax.lax.dynamic_slice_in_dim(Xbuf, q, b, axis=1)
             if srht:
-                cross = srht_rows_dynamic(sketch, q, b)
+                cross = srht_rows_at(
+                    q + jnp.arange(b, dtype=jnp.int32),
+                    jax.lax.dynamic_slice(sketch.signs, (q,), (b,)),
+                    sketch.rows, sketch.n_pad)
             else:
                 cross = jax.lax.dynamic_slice_in_dim(sketch.omega, q, b,
                                                      axis=0)
@@ -303,7 +277,7 @@ class ShardedFitEngine:
                 in_specs=(P(None, ax), P(ax, None), P(ax), aux_spec,
                           P(None, None), P(), P(None, None)),
                 out_specs=(P(ax, None), out2),
-                check_rep=False)(Xbuf, W, rn, aux, c, q, cross)
+                check_vma=False)(Xbuf, W, rn, aux, c, q, cross)
 
         if fused:
             return apply_fn

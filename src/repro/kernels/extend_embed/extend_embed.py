@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.registry import KERNEL_PRECISION
+
 
 def _extend_embed_kernel(xi_ref, pi_ref, xb_ref, o_ref, *, kind: str,
                          gamma: float, degree: int):
@@ -33,6 +35,7 @@ def _extend_embed_kernel(xi_ref, pi_ref, xb_ref, o_ref, *, kind: str,
     xi = xi_ref[...]                    # (p, bm)
     xb = xb_ref[...]                    # (p, w)
     z = jax.lax.dot_general(xi, xb, (((0,), (0,)), ((), ())),
+                            precision=KERNEL_PRECISION,
                             preferred_element_type=jnp.float32)  # (bm, w)
     if kind == "polynomial":
         k = (z + gamma) ** degree
@@ -44,6 +47,7 @@ def _extend_embed_kernel(xi_ref, pi_ref, xb_ref, o_ref, *, kind: str,
         k = z
     pi = pi_ref[...]                    # (r, bm)
     part = jax.lax.dot_general(pi, k, (((1,), (0,)), ((), ())),
+                               precision=KERNEL_PRECISION,
                                preferred_element_type=jnp.float32)  # (r, w)
 
     @pl.when(i == 0)

@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.registry import KERNEL_PRECISION
+
 
 def _fit_sketch_kernel(xi_ref, oi_ref, xb_ref, ocr_ref, vi_ref,
                        acc_ref, dl_ref, rnr_ref, rnc_ref, *, kind: str,
@@ -46,6 +48,7 @@ def _fit_sketch_kernel(xi_ref, oi_ref, xb_ref, ocr_ref, vi_ref,
     xi = xi_ref[...]                    # (p, bm)   X row tile
     xb = xb_ref[...]                    # (p, w)    block columns C
     z = jax.lax.dot_general(xi, xb, (((0,), (0,)), ((), ())),
+                            precision=KERNEL_PRECISION,
                             preferred_element_type=jnp.float32)  # (bm, w)
     if kind == "polynomial":
         k = (z + gamma) ** degree
@@ -57,9 +60,11 @@ def _fit_sketch_kernel(xi_ref, oi_ref, xb_ref, ocr_ref, vi_ref,
         k = z
     oi = oi_ref[...]                    # (bm, rp)  sketch rows of this tile
     acc_part = jax.lax.dot_general(k, oi, (((0,), (0,)), ((), ())),
+                                   precision=KERNEL_PRECISION,
                                    preferred_element_type=jnp.float32)
     ocr = ocr_ref[...]                  # (w, rp)   sketch rows of the block
     delta = jax.lax.dot_general(k, ocr, (((1,), (0,)), ((), ())),
+                                precision=KERNEL_PRECISION,
                                 preferred_element_type=jnp.float32)
     k2 = k * k
     colmask = jax.lax.broadcasted_iota(jnp.int32, (1, k.shape[1]),
@@ -67,6 +72,7 @@ def _fit_sketch_kernel(xi_ref, oi_ref, xb_ref, ocr_ref, vi_ref,
     rnr = jnp.sum(jnp.where(colmask, k2, 0.0), axis=1, keepdims=True)
     vi = vi_ref[...]                    # (8, bm)   row 0 = validity mask
     rnc_part = jax.lax.dot_general(vi, k2, (((1,), (0,)), ((), ())),
+                                   precision=KERNEL_PRECISION,
                                    preferred_element_type=jnp.float32)
 
     @pl.when(i == 0)

@@ -13,7 +13,7 @@ perf argument for fusing stages in VMEM).
 
 For n larger than a VMEM slab, ops.py factorizes H_n = (H_a (x) I_b) .
 (I_a (x) H_b): two grid sweeps of this same kernel around a transpose, so
-the per-sweep working set stays (<= 2^13, 128) floats. Butterflies are VPU
+the per-sweep working set stays (<= 2^10, 128) floats. Butterflies are VPU
 adds/subs on (8,128)-aligned tiles; there is no MXU work in this kernel.
 """
 from __future__ import annotations

@@ -18,9 +18,11 @@ from repro.kernels.fwht.ref import fwht_ref
 from repro.kernels.registry import (KernelContract, KernelEntry,
                                     register_contract, register_kernel)
 
-# Max rows for a single-level slab: 2^13 x 128 lanes x 4B = 4 MiB of VMEM
-# (input + stacked temporaries stay < 16 MiB).
-_MAX_SINGLE = 1 << 13
+# Max rows for a single-level slab. The fused stages keep several
+# slab-sized temporaries live next to the double-buffered in/out blocks:
+# compiled for a v5e (16 MiB scoped VMEM), a (2^11, 128) f32 slab needs
+# 17.9 MiB once the column grid has two steps, a (2^10, 128) slab fits.
+_MAX_SINGLE = 1 << 10
 
 
 def sweep_shapes(n: int, c: int) -> tuple:
@@ -28,6 +30,9 @@ def sweep_shapes(n: int, c: int) -> tuple:
     one slab for n <= _MAX_SINGLE, else the two-level factorization."""
     if n <= _MAX_SINGLE:
         return ((n, c),)
+    if n > _MAX_SINGLE ** 2:
+        raise ValueError(f"FWHT length {n} exceeds the two-level limit "
+                         f"{_MAX_SINGLE ** 2}")
     b = _MAX_SINGLE
     return ((b, (n // b) * c), (n // b, b * c))
 
@@ -61,8 +66,7 @@ def fwht_pallas(x: jnp.ndarray, normalize: bool = True, col_tile: int = 128,
     if n <= _MAX_SINGLE:
         return fwht_1level(x, col_tile, normalize, interp)
     # Two-level: n = a * b with b = _MAX_SINGLE.
-    b = _MAX_SINGLE
-    a = n // b
+    (b, _), (a, _) = sweep_shapes(n, c)
     # Sweep 1: H_b within blocks. (a*b, c) -> treat as a separate columns.
     xb = x.reshape(a, b, c).transpose(1, 0, 2).reshape(b, a * c)
     xb = fwht_1level(xb, col_tile, False, interp)

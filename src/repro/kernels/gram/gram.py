@@ -21,12 +21,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.registry import KERNEL_PRECISION
+
 
 def _gram_kernel(xi_ref, xb_ref, o_ref, *, kind: str, gamma: float,
                  degree: int):
     xi = xi_ref[...]                    # (p, bm)
     xb = xb_ref[...]                    # (p, w)
     z = jax.lax.dot_general(xi, xb, (((0,), (0,)), ((), ())),
+                            precision=KERNEL_PRECISION,
                             preferred_element_type=jnp.float32)  # (bm, w)
     if kind == "polynomial":
         k = (z + gamma) ** degree

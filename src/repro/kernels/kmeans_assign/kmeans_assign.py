@@ -17,11 +17,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.registry import KERNEL_PRECISION
+
 
 def _assign_kernel(y_ref, c_ref, lab_ref, d2_ref, *, k: int):
     y = y_ref[...]                      # (bm, r)
     c = c_ref[...]                      # (k_pad, r)
     z = jax.lax.dot_general(y, c, (((1,), (1,)), ((), ())),
+                            precision=KERNEL_PRECISION,
                             preferred_element_type=jnp.float32)  # (bm, k_pad)
     yn = jnp.sum(y * y, axis=1)[:, None]
     cn = jnp.sum(c * c, axis=1)[None, :]

@@ -18,19 +18,27 @@ def _is_cpu() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def padded_shapes(n: int, r: int, k: int, row_tile: int = 512
+def padded_shapes(n: int, r: int, k: int, row_tile: int = 1024
                   ) -> tuple[int, int, int, int]:
     """(row_tile, n_pad, r_pad, k_pad) the kernel actually runs at — the
     single source of truth for the tiling (assign_pallas pads with
-    exactly these values; memory_contract derives bytes from them)."""
+    exactly these values; memory_contract derives bytes from them).
+
+    The (n_pad,) outputs are 1-D, and XLA lays a 1-D TPU array of 1024
+    or more 32-bit elements out in 1024-element tiles; Mosaic refuses a
+    block that does not match. So a row tile is either the whole padded
+    array (n <= 512) or a multiple of 1024."""
     row_tile = min(row_tile, max(8, 1 << (n - 1).bit_length()))
     n_pad = -(-n // row_tile) * row_tile
+    if row_tile < n_pad and row_tile % 1024:
+        raise ValueError(f"row_tile {row_tile} must be a multiple of 1024 "
+                         f"when it splits the {n_pad} padded rows")
     r_pad = -(-r // 128) * 128
     k_pad = -(-k // 8) * 8
     return row_tile, n_pad, r_pad, k_pad
 
 
-def memory_contract(n: int, r: int, k: int, row_tile: int = 512) -> dict:
+def memory_contract(n: int, r: int, k: int, row_tile: int = 1024) -> dict:
     """Declared HBM byte model for one fused assignment sweep: Y streams
     over the row-tile grid, the centroids stay VMEM-resident, and only
     the two (n,) outputs come back — the (n, k) distance matrix never
@@ -46,7 +54,7 @@ def memory_contract(n: int, r: int, k: int, row_tile: int = 512) -> dict:
 
 
 @functools.partial(jax.jit, static_argnames=("row_tile", "interpret"))
-def assign_pallas(Y: jnp.ndarray, C: jnp.ndarray, row_tile: int = 512,
+def assign_pallas(Y: jnp.ndarray, C: jnp.ndarray, row_tile: int = 1024,
                   interpret: bool | None = None):
     """Fused assignment: Y (n, r), C (k, r) -> (labels (n,), min_d2 (n,)).
 

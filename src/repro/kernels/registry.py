@@ -16,6 +16,15 @@ from __future__ import annotations
 
 from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
+from jax.lax import Precision
+
+# Contraction precision of every f32 matmul inside the Pallas kernels.
+# Mosaic's default rounds f32 operands to bf16 for the MXU; on a v5e that
+# put the fit_sketch sketch 1.8e-3 (relative eigenvalues) away from the
+# f32 jnp path at n=70,000. The kernels state float32, so they contract
+# at full f32 precision.
+KERNEL_PRECISION = Precision.HIGHEST
+
 
 class KernelEntry(NamedTuple):
     """One kernel package's parity contract.

@@ -37,6 +37,8 @@ def main():
     ap.add_argument("--distributed", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     from repro.api import KernelKMeans
     from repro.core import (make_kernel, clustering_accuracy, nmi,
@@ -66,8 +68,9 @@ def main():
         from repro.core.sketch import next_pow2
         from repro.distributed.cluster import \
             distributed_one_pass_kernel_kmeans
+        from repro.serve import data_mesh
         ndev = jax.device_count()
-        mesh = jax.make_mesh((ndev,), ("data",))
+        mesh = data_mesh()
         n_pad = next_pow2(X.shape[1])
         n_pad = max(n_pad, ndev * ((n_pad + ndev - 1) // ndev))
         Xp = jnp.pad(X, ((0, 0), (0, n_pad - X.shape[1])))

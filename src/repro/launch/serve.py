@@ -26,6 +26,8 @@ def main():
     ap.add_argument("--gen", type=int, default=8)
     ap.add_argument("--max-seq", type=int, default=128)
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     from repro.configs import get_config
     from repro.models.registry import get_api

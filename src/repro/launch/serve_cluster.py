@@ -159,6 +159,8 @@ def main():
     ap.add_argument("--bench-out", default="BENCH_serve.json")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.smoke:
         args.n = min(args.n, 2000)
         args.queries = min(args.queries, 1024)
@@ -169,7 +171,8 @@ def main():
     from repro.api import KernelKMeans
     from repro.data import blob_ring
     from repro.serve import (DEFAULT_REGISTRY, ComputePolicy,
-                             ShardedExtender, assign, embed, write_bench)
+                             ShardedExtender, assign, data_mesh, embed,
+                             write_bench)
     from repro.serve.bench import format_bench, run_benches
     from repro.serve.extend import _projection
 
@@ -262,9 +265,7 @@ def main():
     # core contract, checked here on a 1-device mesh (CI's distributed
     # smoke runs the multi-device variant under XLA_FLAGS).
     if backend.startswith("onepass-"):
-        from jax.sharding import Mesh
-        pol = ComputePolicy(mesh=Mesh(np.array(jax.devices()[:1]),
-                                      ("data",)))
+        pol = ComputePolicy(mesh=data_mesh(jax.devices()[:1]))
         est_sh = KernelKMeans(k=args.k, r=args.r, kernel=args.kernel,
                               kernel_params=params, backend=backend,
                               backend_params=backend_params,
@@ -519,7 +520,7 @@ def main():
         if n_dev < 2:
             ap.error(f"--sharded needs >= 2 devices, have {n_dev} (set "
                      "XLA_FLAGS=--xla_force_host_platform_device_count=8)")
-        mesh = jax.make_mesh((n_dev,), ("data",))
+        mesh = data_mesh()
         ext = ShardedExtender(served, mesh)
         Y_sh = ext.embed(Xq[:, :256])
         Y_1d = embed(served, Xq[:, :256])
@@ -551,16 +552,19 @@ def main():
     print(format_bench(bench))
     print(f"wrote {args.bench_out}")
 
-    # Smoke also forces both Pallas serving paths (interpret mode on CPU)
-    # for agreement with the jnp / two-pass paths: the fused kmeans_assign
-    # argmin and the fused gram->projection extend_embed stripe.
+    # Smoke also forces both Pallas serving paths for agreement with the
+    # jnp / two-pass paths: the fused kmeans_assign argmin and the fused
+    # gram->projection extend_embed stripe. Interpret mode only on the
+    # CPU, where Pallas cannot compile; on a chip the compiled kernels
+    # are compared.
     if args.smoke:
+        interp = True if jax.default_backend() == "cpu" else None
         small = Xq[:, :256]
         lab_jnp, _ = assign(served, small,
                             policy=ComputePolicy(assign_fused=False))
         lab_pallas, _ = assign(served, small,
                                policy=ComputePolicy(assign_fused=True,
-                                                    interpret=True))
+                                                    interpret=interp))
         assert np.array_equal(np.asarray(lab_jnp), np.asarray(lab_pallas)), \
             "fused Pallas assignment disagrees with jnp path"
         print("fused Pallas assignment path agrees (256 queries)")
@@ -568,7 +572,7 @@ def main():
                       policy=ComputePolicy(embed_fused=False))
         Y_fused = embed(served, small,
                         policy=ComputePolicy(embed_fused=True,
-                                             interpret=True))
+                                             interpret=interp))
         rel_f = (float(jnp.linalg.norm(Y_fused - Y_two)) /
                  max(float(jnp.linalg.norm(Y_two)), 1e-30))
         assert rel_f <= 1e-5, \

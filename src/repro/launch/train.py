@@ -35,6 +35,8 @@ def main():
                     help="r' for SRHT gradient compression (0 = off)")
     ap.add_argument("--lr", type=float, default=3e-3)
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     if "JAX_COORDINATOR" in os.environ:      # multi-host entry
         jax.distributed.initialize()

@@ -52,8 +52,8 @@ from repro.serve.bench import (benchmark_assign, benchmark_async,
                                write_bench)
 from repro.serve.extend import (Extender, ShardedExtender, assign, embed,
                                 embed_sharded)
-from repro.serve.policy import (ComputePolicy, merge_legacy_kwargs,
-                                resolve_pallas_path)
+from repro.serve.policy import (ComputePolicy, data_mesh,
+                                merge_legacy_kwargs, resolve_pallas_path)
 from repro.serve.latency import LatencyStats
 from repro.serve.registry import (DEFAULT_REGISTRY, ModelRegistry,
                                   SwapReport)
@@ -70,7 +70,8 @@ __all__ = [
     "benchmark_fit_scaling", "benchmark_fused", "benchmark_swap",
     "format_bench", "median_benches", "run_benches", "write_bench",
     "Extender", "ShardedExtender", "assign", "embed", "embed_sharded",
-    "ComputePolicy", "merge_legacy_kwargs", "resolve_pallas_path",
+    "ComputePolicy", "data_mesh", "merge_legacy_kwargs",
+    "resolve_pallas_path",
     "LatencyStats",
     "DEFAULT_REGISTRY", "ModelRegistry", "SwapReport",
     "AsyncBatcher",

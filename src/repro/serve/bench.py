@@ -731,10 +731,8 @@ def benchmark_fit_scaling(model: FittedModel, ns: Sequence[int] = (128, 256,
     smoke). Each row carries the `_fit_block_traffic` bytes-moved model,
     which is backend-independent.
     """
-    from jax.sharding import Mesh
-
     from repro.api import KernelKMeans
-    from repro.serve.policy import ComputePolicy
+    from repro.serve.policy import ComputePolicy, data_mesh
 
     key = key if key is not None else jax.random.PRNGKey(0)
     spec = model.spec
@@ -742,8 +740,7 @@ def benchmark_fit_scaling(model: FittedModel, ns: Sequence[int] = (128, 256,
                else "onepass-srht")
     chunk = min(block or spec.block, min(int(n) for n in ns))
     if policy is None:
-        policy = ComputePolicy(
-            mesh=Mesh(np.asarray(jax.devices()), ("data",)))
+        policy = ComputePolicy(mesh=data_mesh())
 
     def one_pass(n_chunks, capacity, X, pol):
         est = KernelKMeans(k=spec.k, r=spec.r, kernel=spec.kernel,

@@ -59,7 +59,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.kernels_fn import stripe_iterator
@@ -349,14 +349,13 @@ class ShardedExtender:
                         degree=degree, interpret=interp)
                 else:
                     part = prl @ kern(xl, xbl)           # (r, block)
-                return jax.lax.psum(part, ax)[None]      # (1, r, block)
+                return jax.lax.psum(part, ax)            # (r, block)
 
-            out = shard_map(body, mesh=mesh,
-                            in_specs=(P(None, ax), P(None, ax),
-                                      P(None, None)),
-                            out_specs=P(ax, None, None),
-                            check_rep=False)(Xt_sh, proj_sh, xb)
-            return out[0]                                # (r, block)
+            return shard_map(body, mesh=mesh,
+                             in_specs=(P(None, ax), P(None, ax),
+                                       P(None, None)),
+                             out_specs=P(None, None),
+                             check_vma=False)(Xt_sh, proj_sh, xb)
 
         self._stripe_embed = stripe_embed
 

@@ -33,9 +33,11 @@ from __future__ import annotations
 
 import dataclasses
 import warnings
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
 
 def resolve_pallas_path(fused: Optional[bool], interpret: Optional[bool],
@@ -82,6 +84,14 @@ def resolve_pallas_path(fused: Optional[bool], interpret: Optional[bool],
     return True, bool(interpret) if interpret is not None else False
 
 
+def data_mesh(devices: Optional[Sequence] = None) -> Mesh:
+    """1-D "data" mesh over `devices` (default: every device) in Auto
+    sharding mode, the mode the sharded fit and serving paths are
+    written for (jax.make_mesh defaults to Explicit axes)."""
+    devices = jax.devices() if devices is None else list(devices)
+    return Mesh(np.array(devices), ("data",), axis_types=(AxisType.Auto,))
+
+
 @dataclasses.dataclass(frozen=True)
 class ComputePolicy:
     """Frozen compute-path selection, shared by fit and serve.
@@ -100,10 +110,17 @@ class ComputePolicy:
     mesh_axis: str = "data"
 
     def __post_init__(self):
-        if self.mesh is not None and \
-                self.mesh_axis not in self.mesh.axis_names:
+        if self.mesh is None:
+            return
+        if self.mesh_axis not in self.mesh.axis_names:
             raise ValueError(f"mesh has no axis {self.mesh_axis!r}; "
                              f"have {self.mesh.axis_names}")
+        types = dict(zip(self.mesh.axis_names, self.mesh.axis_types))
+        if types[self.mesh_axis] == AxisType.Explicit:
+            raise ValueError(
+                f"mesh axis {self.mesh_axis!r} is Explicit; the sharded "
+                f"paths need Auto sharding — build the mesh with "
+                f"data_mesh() or axis_types=(AxisType.Auto, ...)")
 
     # -- resolution (the old resolve_pallas_path call sites) -------------
 

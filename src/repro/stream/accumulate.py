@@ -13,7 +13,7 @@ and the sketch update splits along it:
                                             zero-padded border columns
     W[:q]    += Kc[:q] @ Omega[q:q+b]       symmetric cross-term into the
                                             old rows, via the materialized
-                                            Omega row slice (srht_rows)
+                                            Omega row slice (srht_rows_at)
 
 Row norms of K accumulate the same way, giving a streaming estimate of
 ||K||_F^2 (and hence of the approximation error) for free.
@@ -33,6 +33,7 @@ when data arrives.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, Optional, Union
 
 import jax
@@ -41,9 +42,50 @@ import jax.numpy as jnp
 from repro.core.kernels_fn import KernelFn
 from repro.core.sketch import (GaussianSketch, LowRankEig, SRHT,
                                make_gaussian, make_srht, one_pass_core,
-                               srht_apply_t, srht_rows)
+                               srht_apply_t, srht_rows_at)
 
 Sketch = Union[SRHT, GaussianSketch]
+
+
+@functools.partial(jax.jit, static_argnames=("b", "n_pad", "kind", "gamma",
+                                             "degree", "interpret"))
+def _fused_block_update(X, W, row_norms2, aux, rows, q, *, b: int,
+                        n_pad: int, kind: str, gamma: float, degree: int,
+                        interpret: bool):
+    """Fold columns [q, q+b) of X (p, m) into (W, row_norms2) through the
+    fit_sketch kernel. The offset q is traced, so every block of one
+    width shares this executable: the kernel sweeps all m columns with
+    the Omega rows and validity mask of rows >= q+b zeroed, which is the
+    kernel's own exact-padding contract. aux is the SRHT sign diagonal
+    (with `rows` its sampled rows) or, when rows is None, the dense
+    Gaussian Omega."""
+    from repro.kernels.fit_sketch.ops import fit_sketch_pallas
+
+    m = X.shape[1]
+    gids = jnp.arange(m, dtype=jnp.int32)
+    bids = q + jnp.arange(b, dtype=jnp.int32)
+    valid = gids < q + b
+    C = jax.lax.dynamic_slice_in_dim(X, q, b, axis=1)
+    if rows is None:
+        Omega = aux[:m]
+        cross = jax.lax.dynamic_slice_in_dim(aux, q, b, axis=0)
+    else:
+        Omega = srht_rows_at(gids, aux[:m], rows, n_pad)
+        cross = srht_rows_at(bids, jax.lax.dynamic_slice(aux, (q,), (b,)),
+                             rows, n_pad)
+    Omega = jnp.where(valid[:, None], Omega, 0.0)
+    V = jnp.zeros((8, m), jnp.float32).at[0].set(valid.astype(jnp.float32))
+    new_rows, delta, rn_rows, rn_cols = fit_sketch_pallas(
+        X, Omega, C, cross, V, kind=kind, gamma=gamma, degree=degree,
+        interpret=interpret)
+    applied = gids < q
+    Wm = jnp.where(applied[:, None], W[:m] + delta, W[:m])
+    rnm = jnp.where(applied, row_norms2[:m] + rn_rows, row_norms2[:m])
+    W = jax.lax.dynamic_update_slice(W, Wm, (0, 0))
+    row_norms2 = jax.lax.dynamic_update_slice(row_norms2, rnm, (0,))
+    W = jax.lax.dynamic_update_slice(W, new_rows, (q, 0))
+    row_norms2 = jax.lax.dynamic_update_slice(row_norms2, rn_cols, (q,))
+    return W, row_norms2
 
 
 class SketchAccumulator:
@@ -277,60 +319,62 @@ class SketchAccumulator:
             return self._engine.apply(W, row_norms2, q, b)
         if self._fit_fused:
             return self._apply_fused(W, row_norms2, q, b)
-        C = self._X[:, q:q + b]
-        Kc = self.kernel(self._X[:, :q + b], C)            # (q+b, b)
+        # Every shape below is fixed for the whole pass (m columns added,
+        # capacity rows) and the offset q is a device scalar, so each
+        # eager op compiles once per fit, not once per block. The
+        # border is computed against all m columns with the rows past
+        # q+b zeroed; each entry is the value kappa(X[:, :q+b], C)
+        # would give, and the zero rows add nothing to any reduction.
+        X = self._X
+        m = int(X.shape[1])
+        qd = jnp.asarray(q, jnp.int32)
+        rows = jnp.arange(m, dtype=jnp.int32)
+        C = jax.lax.dynamic_slice_in_dim(X, qd, b, axis=1)
+        Kc = jnp.where((rows < qd + b)[:, None], self.kernel(X, C), 0.0)
+        Kp = jnp.zeros((self.capacity, b), jnp.float32).at[:m].set(Kc)
         if isinstance(self.sketch, SRHT):
-            Kp = jnp.zeros((self.capacity, b),
-                           jnp.float32).at[:q + b].set(Kc)
             new_rows = srht_apply_t(self.sketch, Kp, self.fwht_fn).T
-            cross = srht_rows(self.sketch, q, q + b)
+            cross = srht_rows_at(
+                qd + jnp.arange(b, dtype=jnp.int32),
+                jax.lax.dynamic_slice(self.sketch.signs, (qd,), (b,)),
+                self.sketch.rows, self.sketch.n_pad)
         else:
-            new_rows = Kc.T @ self.sketch.omega[:q + b]
-            cross = self.sketch.omega[q:q + b]
-        W = W.at[q:q + b].set(new_rows)
-        # Column norms over a statically zero-padded stripe: the
-        # reduction length is shape-stable (n_pad / capacity) rather
-        # than q+b, so the mesh-sharded fit engine (distributed/fit.py)
-        # — which can only ever reduce over its fixed padded row space —
-        # reproduces these bits exactly on one device. The trailing
-        # zero rows are value-neutral.
+            new_rows = Kp.T @ self.sketch.omega
+            cross = jax.lax.dynamic_slice_in_dim(self.sketch.omega, qd, b,
+                                                 axis=0)
+        # Norms over a zero-padded stripe of the sharded engine's fixed
+        # row space (n_pad / capacity): the same reduction lengths, so
+        # distributed/fit.py reproduces these bits on one device.
         n_red = (self.sketch.n_pad if isinstance(self.sketch, SRHT)
                  else self.capacity)
-        Kf = jnp.zeros((n_red, b), jnp.float32).at[:q + b].set(Kc)
-        K2f = Kf * Kf
-        row_norms2 = row_norms2.at[q:q + b].set(jnp.sum(K2f, axis=0))
-        if q:
-            W = W.at[:q].add(Kc[:q] @ cross)
-            row_norms2 = row_norms2.at[:q].add(
-                jnp.sum(Kc[:q] * Kc[:q], axis=1))
+        K2 = jnp.zeros((n_red, b), jnp.float32).at[:m].set(Kc)
+        K2 = K2 * K2
+        applied = rows < qd
+        W_m = jnp.where(applied[:, None], W[:m] + Kc @ cross, W[:m])
+        rn_m = jnp.where(applied, row_norms2[:m] + jnp.sum(K2, axis=1)[:m],
+                         row_norms2[:m])
+        W = jax.lax.dynamic_update_slice(W.at[:m].set(W_m), new_rows,
+                                         (qd, 0))
+        row_norms2 = jax.lax.dynamic_update_slice(
+            row_norms2.at[:m].set(rn_m), jnp.sum(K2, axis=0), (qd,))
         return W, row_norms2
 
     def _apply_fused(self, W, row_norms2, q, b):
         """Single-host block update through the fused fit_sketch Pallas
         kernel: gram-stripe -> sketch-accumulate in one pass with the
-        accumulator VMEM-resident. Materializes the Omega row prefix
-        (the price of trading the FWHT for an MXU contraction; the
-        distributed engine shards that slab instead)."""
-        from repro.kernels.fit_sketch.ops import fit_sketch_pallas
-
+        accumulator VMEM-resident. Materializes the Omega rows of every
+        column added so far (the price of trading the FWHT for an MXU
+        contraction; the distributed engine shards that slab instead)."""
         kind, gamma, degree = self.kernel_statics
-        Xpre = self._X[:, :q + b]
-        C = self._X[:, q:q + b]
         if isinstance(self.sketch, SRHT):
-            Omega = srht_rows(self.sketch, 0, q + b)
-            cross = srht_rows(self.sketch, q, q + b)
+            aux, rows = self.sketch.signs, self.sketch.rows
         else:
-            Omega = self.sketch.omega[:q + b]
-            cross = self.sketch.omega[q:q + b]
-        new_rows, delta, rn_rows, rn_cols = fit_sketch_pallas(
-            Xpre, Omega, C, cross, kind=kind, gamma=float(gamma),
-            degree=int(degree), interpret=self._fit_interpret)
-        W = W.at[q:q + b].set(new_rows)
-        row_norms2 = row_norms2.at[q:q + b].set(rn_cols)
-        if q:
-            W = W.at[:q].add(delta[:q])
-            row_norms2 = row_norms2.at[:q].add(rn_rows[:q])
-        return W, row_norms2
+            aux, rows = self.sketch.omega, None
+        return _fused_block_update(
+            self._X, W, row_norms2, aux, rows, jnp.asarray(q, jnp.int32),
+            b=int(b), n_pad=int(getattr(self.sketch, "n_pad", 0)),
+            kind=kind, gamma=float(gamma), degree=int(degree),
+            interpret=self._fit_interpret)
 
     def _effective_state(self):
         """(W, row_norms2, n_eff) with the staged tail applied on a COPY

@@ -10,14 +10,21 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+
+
+def _mesh(shape, names):
+    """A mesh in Auto sharding mode, the mode the library is written
+    for (jax.make_mesh defaults to Explicit axes)."""
+    return jax.make_mesh(shape, names,
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
 def check_distributed_fwht():
     from repro.distributed.dfwht import distributed_fwht
     from repro.core.sketch import fwht
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = _mesh((8,), ("data",))
     for n, c in [(64, 4), (512, 3), (8, 1)]:
         x = jax.random.normal(jax.random.PRNGKey(n), (n, c))
         xs = jax.device_put(x, NamedSharding(mesh, P("data", None)))
@@ -32,7 +39,7 @@ def check_dfwht_on_2d_mesh():
     from repro.distributed.dfwht import distributed_fwht
     from repro.core.sketch import fwht
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = _mesh((4, 2), ("data", "model"))
     x = jax.random.normal(jax.random.PRNGKey(0), (128, 2))
     xs = jax.device_put(x, NamedSharding(mesh, P("data", None)))
     got = distributed_fwht(xs, mesh, "data")
@@ -51,7 +58,7 @@ def check_sharded_train_step():
     from repro.launch import specs
     from repro.launch.mesh import dp_axes
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = _mesh((2, 2), ("data", "model"))
     cfg = get_config("mixtral-8x7b", smoke=True)
     api = get_api(cfg)
     state = tsteps.init_train_state(jax.random.PRNGKey(0), cfg, api, tp=2)
@@ -96,7 +103,7 @@ def check_sharded_vs_single_device_loss():
     loss_1dev = float(tsteps.cross_entropy(logits_1dev, batch["labels"]))
 
     from repro.distributed import sharding as shd
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = _mesh((4, 2), ("data", "model"))
     ps = shd.param_pspecs(jax.eval_shape(lambda: params), mesh)
     bs = shd.batch_pspecs(jax.eval_shape(lambda: batch), mesh)
 
@@ -117,10 +124,10 @@ def check_sharded_vs_single_device_loss():
 def check_sketched_allreduce_pmean():
     """Sketch all-reduce inside shard_map: mean of per-shard gradients
     (projected) equals projection of the mean."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.distributed.compression import (sketch_params, compress,
                                                decompress)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = _mesh((8,), ("data",))
     n = 256
     g = jax.random.normal(jax.random.PRNGKey(0), (8, n))
     signs, rows = sketch_params(jax.random.PRNGKey(1), n, 32)
@@ -131,7 +138,7 @@ def check_sketched_allreduce_pmean():
         return decompress(s, signs, rows, n)[None]
 
     out = shard_map(body, mesh=mesh, in_specs=P("data", None),
-                    out_specs=P("data", None), check_rep=False)(g)
+                    out_specs=P("data", None), check_vma=False)(g)
     want = decompress(compress(jnp.mean(g, 0), signs, rows), signs, rows, n)
     np.testing.assert_allclose(np.asarray(out[0]), np.asarray(want),
                                rtol=1e-3, atol=1e-4)
@@ -147,7 +154,7 @@ def check_distributed_clustering():
                             clustering_accuracy)
     from repro.data import blob_ring
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = _mesh((8,), ("data",))
     n = 1024                                 # power of two (pre-padded)
     X, labels_true = blob_ring(jax.random.PRNGKey(0), n=n)
     kern = polynomial_kernel(gamma=0.0, degree=2)
@@ -177,7 +184,7 @@ def check_sharded_extend():
     from repro.serve import (AsyncBatcher, MicroBatcher, ShardedExtender,
                              assign, embed)
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = _mesh((8,), ("data",))
     X, _ = blob_ring(jax.random.PRNGKey(0), n=250)
     Xq = jax.random.normal(jax.random.PRNGKey(2), (2, 101)) * 1.5
     # rbf included: kappa(0, x) != 0, so this exercises the zero-column

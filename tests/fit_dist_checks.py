@@ -111,7 +111,27 @@ def check_resume_from_artifact_bitwise_on_mesh():
     print("2-device artifact resume bitwise ok")
 
 
+def check_chip_smoke_mesh_phase():
+    """chip_smoke.py's mesh phase at a tiny size, kernels interpreted."""
+    import pathlib
+    import sys
+
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
+    import chip_smoke as cs
+    from repro.data import gaussian_blobs
+    from repro.serve import ComputePolicy
+
+    clock, check = cs.CompileClock(), cs.Checks()
+    k_data, k_gamma, k_fit = jax.random.split(jax.random.PRNGKey(0), 3)
+    X, _ = gaussian_blobs(k_data, n=2048, p=32, k=cs.K)
+    cs.mesh_phase(X, cs.rbf_gamma(X, k_gamma), k_fit, 2,
+                  ComputePolicy(interpret=True), check, clock)
+    assert check.failed == [], check.failed
+    print("chip_smoke mesh phase ok on 2 devices")
+
+
 if __name__ == "__main__":
+    check_chip_smoke_mesh_phase()
     check_two_device_fit_close_to_single_host()
     check_chunk_invariance_bitwise_on_mesh()
     check_resume_from_artifact_bitwise_on_mesh()

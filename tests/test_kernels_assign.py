@@ -35,10 +35,15 @@ def test_assign_padded_centroids_never_win():
 
 
 def test_assign_row_tiles():
-    Y = jax.random.normal(jax.random.PRNGKey(1), (777, 9))
+    """Multi-tile grids (3, 2 and 1 steps) agree with the oracle; a tile
+    that splits the rows must be a multiple of 1024, the 1-D output
+    layout the TPU compiler demands."""
+    Y = jax.random.normal(jax.random.PRNGKey(1), (2500, 9))
     C = jax.random.normal(jax.random.PRNGKey(2), (11, 9))
     want = assign_ref(Y, C)
-    for rt in (64, 256, 1024):
+    for rt in (1024, 2048, 4096):
         labels, d2 = assign_pallas(Y, C, row_tile=rt, interpret=True)
         np.testing.assert_allclose(np.asarray(d2), np.asarray(want[1]),
                                    rtol=1e-4, atol=1e-4)
+    with pytest.raises(ValueError, match="multiple of 1024"):
+        assign_pallas(Y, C, row_tile=256, interpret=True)

@@ -181,6 +181,17 @@ def test_no_legacy_kwargs_no_warning(blobs):
         Extender(est.model_, policy=ComputePolicy())
 
 
+def test_policy_rejects_explicit_mesh_axis():
+    """jax.make_mesh defaults to Explicit axes; the sharded paths are
+    written for Auto sharding, so the policy says so up front."""
+    from repro.serve import data_mesh
+    explicit = jax.make_mesh((1,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Explicit,))
+    with pytest.raises(ValueError, match="Explicit"):
+        ComputePolicy(mesh=explicit)
+    assert ComputePolicy(mesh=data_mesh(jax.devices()[:1])).shards == 1
+
+
 # ---------------------------------------------------------------------------
 # fused fit path (fp tolerance, interpret mode)
 # ---------------------------------------------------------------------------

@@ -14,7 +14,7 @@ import pytest
 from repro.api import KernelKMeans
 from repro.core.kmeans import kmeans
 from repro.core.metrics import clustering_accuracy
-from repro.core.sketch import make_srht, srht_apply_t, srht_rows
+from repro.core.sketch import make_srht, srht_apply_t, srht_rows_at
 from repro.data import blob_ring
 from repro.distributed.compression import (dequantize_state, int8_decode,
                                            int8_encode, quantize_state)
@@ -176,9 +176,11 @@ def test_srht_rows_matches_dense_apply():
     n = 37
     srht = make_srht(jax.random.PRNGKey(9), n, 16)
     dense = srht_apply_t(srht, jnp.eye(n, dtype=jnp.float32)).T  # (n, r')
-    np.testing.assert_array_equal(np.asarray(srht_rows(srht, 0, n)),
-                                  np.asarray(dense))
-    np.testing.assert_array_equal(np.asarray(srht_rows(srht, 5, 21)),
+    def rows(start, stop):
+        return srht_rows_at(jnp.arange(start, stop, dtype=jnp.int32),
+                            srht.signs[start:stop], srht.rows, srht.n_pad)
+    np.testing.assert_array_equal(np.asarray(rows(0, n)), np.asarray(dense))
+    np.testing.assert_array_equal(np.asarray(rows(5, 21)),
                                   np.asarray(dense[5:21]))
 
 
