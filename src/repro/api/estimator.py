@@ -40,6 +40,7 @@ from repro.core.kmeans import kmeans
 from repro.serve import extend
 from repro.serve.artifact import (ClusteringSpec, FittedModel,
                                   _cached_kernel, load_model, save_model)
+from repro.spans import span
 
 # fit_model's historical default for the paper's primary kernel.
 _KERNEL_DEFAULTS = {"polynomial": {"gamma": 0.0, "degree": 2}}
@@ -170,38 +171,44 @@ class KernelKMeans:
 
     def _package(self, spec: ClusteringSpec, X: jnp.ndarray, U, eigvals,
                  centroids, state: Dict, ref=None) -> FittedModel:
-        return FittedModel(
-            spec=spec, X_train=jnp.asarray(X, jnp.float32),
-            U=U, eigvals=eigvals, centroids=centroids,
-            sketch_signs=state.get("sketch_signs"),
-            sketch_rows=state.get("sketch_rows"),
-            sketch_omega=state.get("sketch_omega"),
-            landmarks=ref,
-            landmark_idx=state.get("landmark_idx"),
-            stream_w=state.get("stream_w"),
-            stream_row_norms2=state.get("stream_row_norms2"),
-            stream_counts=state.get("stream_counts"))
+        with span("fit.package"):
+            return FittedModel(
+                spec=spec, X_train=jnp.asarray(X, jnp.float32),
+                U=U, eigvals=eigvals, centroids=centroids,
+                sketch_signs=state.get("sketch_signs"),
+                sketch_rows=state.get("sketch_rows"),
+                sketch_omega=state.get("sketch_omega"),
+                landmarks=ref,
+                landmark_idx=state.get("landmark_idx"),
+                stream_w=state.get("stream_w"),
+                stream_row_norms2=state.get("stream_row_norms2"),
+                stream_counts=state.get("stream_counts"))
 
     def fit(self, X: jnp.ndarray,
             key: Union[None, int, jax.Array] = None) -> "KernelKMeans":
         """Fit on X (p, n); `key` may be a PRNGKey, an int seed, or None
         (seed 0). Returns self."""
-        key = _as_key(key)
-        spec = self._make_spec(n=X.shape[1], p=X.shape[0])
-        kern = self._kernel_fn()
-        k_backend, k_km = jax.random.split(key)
-        emb = be.get_backend(self.backend).fit(
-            k_backend, kern, X, self.r, block=self.block,
-            **self.backend_params, **self._policy_kwargs(spec))
-        km = kmeans(k_km, emb.Y.T, self.k, n_restarts=self.n_restarts,
-                    max_iter=self.max_iter)
-        self.model_ = self._package(spec, X, emb.U, emb.eigvals,
-                                    km.centroids, emb.arrays, ref=emb.ref)
+        with span("fit", n=int(X.shape[1]), p=int(X.shape[0]),
+                  backend=self.backend):
+            key = _as_key(key)
+            spec = self._make_spec(n=X.shape[1], p=X.shape[0])
+            kern = self._kernel_fn()
+            k_backend, k_km = jax.random.split(key)
+            emb = be.get_backend(self.backend).fit(
+                k_backend, kern, X, self.r, block=self.block,
+                **self.backend_params, **self._policy_kwargs(spec))
+            with span("fit.kmeans"):
+                km = kmeans(k_km, emb.Y.T, self.k,
+                            n_restarts=self.n_restarts,
+                            max_iter=self.max_iter)
+            self.model_ = self._package(spec, X, emb.U, emb.eigvals,
+                                        km.centroids, emb.arrays,
+                                        ref=emb.ref)
+            self.inertia_ = float(km.objective)
         self.labels_ = km.labels
         self.embedding_ = emb.Y
         self.eigvals_ = emb.eigvals
         self.centroids_ = km.centroids
-        self.inertia_ = float(km.objective)
         self.spec_ = spec
         self._extender = None
         self._acc = None          # a fresh fit retires live stream state

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.spans import span
+
 
 def _tree_paths(tree) -> List[str]:
     paths = []
@@ -44,7 +46,9 @@ def save_checkpoint(ckpt_dir: str, step: int, state: Any,
     tmp = base / f"step_{step}.tmp"
     final = base / f"step_{step}"
     leaves, treedef = jax.tree.flatten(state)
-    host_leaves = [np.asarray(jax.device_get(leaf)) for leaf in leaves]
+    with span("store.fetch") as fetch_span:
+        host_leaves = [np.asarray(jax.device_get(leaf)) for leaf in leaves]
+        fetch_span.set_metadata(bytes=sum(leaf.nbytes for leaf in host_leaves))
     manifest = {
         "step": step,
         "time": time.time(),
@@ -55,15 +59,17 @@ def save_checkpoint(ckpt_dir: str, step: int, state: Any,
     }
 
     def write():
-        if tmp.exists():
-            shutil.rmtree(tmp)
-        tmp.mkdir(parents=True)
-        for i, leaf in enumerate(host_leaves):
-            np.save(tmp / f"leaf_{i}.npy", leaf)
-        (tmp / "manifest.json").write_text(json.dumps(manifest))
-        if final.exists():
-            shutil.rmtree(final)
-        os.replace(tmp, final)
+        with span("store.write",
+                  bytes=sum(leaf.nbytes for leaf in host_leaves)):
+            if tmp.exists():
+                shutil.rmtree(tmp)
+            tmp.mkdir(parents=True)
+            for i, leaf in enumerate(host_leaves):
+                np.save(tmp / f"leaf_{i}.npy", leaf)
+            (tmp / "manifest.json").write_text(json.dumps(manifest))
+            if final.exists():
+                shutil.rmtree(final)
+            os.replace(tmp, final)
 
     if blocking:
         write()

@@ -60,6 +60,7 @@ import jax.numpy as jnp
 from repro.core.kernels_fn import KernelFn, make_kernel
 from repro.distributed import compression
 from repro.distributed import checkpoint as ckpt
+from repro.spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -254,9 +255,11 @@ def save_model(model: FittedModel, artifact_dir: str,
     # Explicit leaf names, in checkpoint leaf order (jax flattens a dict
     # in sorted-key order) — load_model must not have to reverse-engineer
     # names out of jax.tree_util.keystr formatting.
-    (base / "leaves.json").write_text(
-        json.dumps({"names": sorted(state), "quantized": quantized}))
-    (base / "spec.json").write_text(model.spec.to_json())
+    leaves = json.dumps({"names": sorted(state), "quantized": quantized})
+    spec = model.spec.to_json()
+    with span("store.write", bytes=len(leaves) + len(spec)):
+        (base / "leaves.json").write_text(leaves)
+        (base / "spec.json").write_text(spec)
     return str(base)
 
 

@@ -31,6 +31,7 @@ from repro.core.sketch import next_pow2
 from repro.serve import extend
 from repro.serve.artifact import FittedModel
 from repro.serve.policy import ComputePolicy, merge_legacy_kwargs
+from repro.spans import span
 
 
 def bucket_size(b: int, min_bucket: int = 8, max_bucket: int = 1024) -> int:
@@ -117,25 +118,31 @@ class MicroBatcher:
                          ) -> Tuple[np.ndarray, np.ndarray]:
         w = chunk.shape[1]
         bsz = bucket_size(w, self.min_bucket, self.max_bucket)
-        padded = (chunk if w == bsz
-                  else jnp.pad(chunk, ((0, 0), (0, bsz - w))))
-        if self.sharded:
-            # Sharded path: stripe width is baked into the one compiled
-            # sharded executable at ShardedExtender construction.
-            lab, d2 = self.extender.assign(padded)
-        else:
-            # Narrow the gram stripe to the bucket: a bucket-8 request
-            # must not pay an n x block (e.g. 512-wide) kernel stripe.
-            # bsz is already pow-2-clamped, so stripe widths — and hence
-            # compiled executables — stay bounded by the bucket count.
-            lab, d2 = self.extender.assign(padded,
-                                           block=min(self.block, bsz))
+        with span("serve.dispatch", bucket=bsz):
+            padded = (chunk if w == bsz
+                      else jnp.pad(chunk, ((0, 0), (0, bsz - w))))
+            if self.sharded:
+                # Sharded path: stripe width is baked into the one
+                # compiled sharded executable at ShardedExtender
+                # construction.
+                lab, d2 = self.extender.assign(padded)
+            else:
+                # Narrow the gram stripe to the bucket: a bucket-8
+                # request must not pay an n x block (e.g. 512-wide)
+                # kernel stripe. bsz is already pow-2-clamped, so stripe
+                # widths — and hence compiled executables — stay bounded
+                # by the bucket count.
+                lab, d2 = self.extender.assign(padded,
+                                               block=min(self.block, bsz))
         self.stats["queries"] += w
         self.stats["padded_queries"] += bsz - w
         self.stats["batches"] += 1
         self.stats["bucket_hits"][bsz] = \
             self.stats["bucket_hits"].get(bsz, 0) + 1
-        return np.asarray(lab[:w]), np.asarray(d2[:w])
+        # Waits for the device, copies back (and compiles the slices of
+        # a width not seen before).
+        with span("serve.fetch"):
+            return np.asarray(lab[:w]), np.asarray(d2[:w])
 
     def warm(self, buckets) -> List[int]:
         """Compile the executables for the given bucket widths now.
@@ -182,7 +189,8 @@ class MicroBatcher:
         if not self._pending:
             return []
         widths = [x.shape[1] for x in self._pending]
-        big = jnp.asarray(np.concatenate(self._pending, axis=1))
+        with span("serve.coalesce", width=sum(widths)):
+            big = jnp.asarray(np.concatenate(self._pending, axis=1))
         self._pending = []
         labels, d2 = self.assign_batch(big)
         out, off = [], 0

@@ -43,6 +43,7 @@ fused=/embed_fused=/mesh= spellings forward the same way).
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from concurrent.futures import Future
@@ -53,13 +54,17 @@ import numpy as np
 from repro.serve.artifact import FittedModel
 from repro.serve.batcher import MicroBatcher, bucket_size
 from repro.serve.latency import LatencyStats
+from repro.spans import span
 
 
 class _Pending(NamedTuple):
-    """One queued request: payload + future + its enqueue timestamp."""
+    """One queued request: payload + future + its enqueue timestamp +
+    its request id (the batcher's submit counter; ids are FIFO, so a
+    flush's first id and request count name every request in it)."""
     Xq: np.ndarray
     future: Future
     enqueue_ts: float
+    rid: int
 
 
 class AsyncBatcher:
@@ -93,6 +98,7 @@ class AsyncBatcher:
         # annotations below; mutations of annotated fields outside
         # `with self._lock` are build failures (rules L001/L002).
         self._queue: List[_Pending] = []      # guarded-by: _lock
+        self._next_rid = 0                    # guarded-by: _lock
         # Per-bucket deadline overrides (milliseconds), keyed by the pow-2
         # execution bucket the CURRENT pending window would coalesce into.
         # This is the knob the fleet tier's AdaptiveWaitController turns:
@@ -122,23 +128,28 @@ class AsyncBatcher:
         the full-batch trigger — so a saturating client never waits on the
         deadline.
         """
-        Xq = self.batcher.validate_request(Xq)
-        fut: Future = Future()
-        with self._lock:
-            # Checked under the lock so a submit racing stop() either
-            # lands in the queue stop() is about to flush, or raises —
-            # it can never enqueue into a retired, pump-less batcher
-            # where the future would be stranded forever.
-            if self._stopped:
-                raise RuntimeError(
-                    "submit() on a stopped AsyncBatcher: nothing would "
-                    "ever flush this request (after a hot-swap, get the "
-                    "current scheduler from the registry)")
-            self._queue.append(_Pending(Xq, fut, self.clock()))
-            full = self._pending_width_locked() >= self.batcher.max_bucket
-        if full:
-            self.flush()
-        return fut
+        with span("serve.submit") as submit_span:
+            Xq = self.batcher.validate_request(Xq)
+            fut: Future = Future()
+            with self._lock:
+                # Checked under the lock so a submit racing stop() either
+                # lands in the queue stop() is about to flush, or raises —
+                # it can never enqueue into a retired, pump-less batcher
+                # where the future would be stranded forever.
+                if self._stopped:
+                    raise RuntimeError(
+                        "submit() on a stopped AsyncBatcher: nothing would "
+                        "ever flush this request (after a hot-swap, get the "
+                        "current scheduler from the registry)")
+                rid = self._next_rid
+                self._next_rid += 1
+                self._queue.append(_Pending(Xq, fut, self.clock(), rid))
+                full = (self._pending_width_locked()
+                        >= self.batcher.max_bucket)
+            submit_span.set_metadata(rid=rid)
+            if full:
+                self._flush("full")
+            return fut
 
     def _pending_width_locked(self) -> int:
         return sum(p.Xq.shape[1] for p in self._queue)
@@ -201,7 +212,7 @@ class AsyncBatcher:
         test) calls poll() at whatever cadence it likes; the pump thread
         is just poll() in a loop.
         """
-        return self.flush() if self.due() else 0
+        return self._flush("deadline") if self.due() else 0
 
     def flush(self) -> int:
         """Run all pending requests now; returns requests completed.
@@ -212,57 +223,72 @@ class AsyncBatcher:
         on compute failure every future in the batch carries the
         exception instead of the batch dying silently.
         """
-        with self._flush_lock:
-            with self._lock:
-                batch, self._queue = self._queue, []
-            if not batch:
-                return 0
-            flush_ts = self.clock()
-            try:
+        return self._flush("manual")
+
+    def _flush(self, trigger: str) -> int:
+        """flush(), with what set it off ("full", "deadline", "stop" or
+        "manual") named on its serve.flush span."""
+        # serve.flush and serve.resolve close after the futures resolve,
+        # outside the flush lock, so they are held on an exit stack.
+        with contextlib.ExitStack() as spans:
+            with self._flush_lock:
+                with self._lock:
+                    batch, self._queue = self._queue, []
+                if not batch:
+                    return 0
+                # The pow-2 execution bucket this flush runs through: the
+                # coalesced width, bucketed by the inner batcher's policy
+                # (oversized batches chunk into max_bucket pieces, so the
+                # clamp is also the dominant executable). Keys the
+                # per-bucket latency breakdown.
+                width = sum(p.Xq.shape[1] for p in batch)
+                bucket = bucket_size(width, self.batcher.min_bucket,
+                                     self.batcher.max_bucket)
+                spans.enter_context(span(
+                    "serve.flush", trigger=trigger, first_rid=batch[0].rid,
+                    requests=len(batch), width=width, bucket=bucket))
+                flush_ts = self.clock()
+                try:
+                    for p in batch:
+                        self.batcher.submit(p.Xq)
+                    results = self.batcher.drain()
+                except Exception as exc:             # pragma: no cover
+                    for p in batch:
+                        if p.future.set_running_or_notify_cancel():
+                            p.future.set_exception(exc)
+                    raise
+                # drain() must return exactly one result per request
+                # handed to it; a mismatch means something enqueued on
+                # the inner batcher directly and a silent zip would
+                # scatter results to the wrong futures.
+                if len(results) != len(batch):       # pragma: no cover
+                    exc = RuntimeError(
+                        f"flush expected {len(batch)} results, drained "
+                        f"{len(results)}: the inner MicroBatcher had "
+                        f"foreign pending requests")
+                    for p in batch:
+                        if p.future.set_running_or_notify_cancel():
+                            p.future.set_exception(exc)
+                    raise exc
+                complete_ts = self.clock()
+                spans.enter_context(span("serve.resolve"))
+                # LatencyStats mutation stays inside the flush lock:
+                # record() is read-modify-write on histogram counts, and
+                # a pump-thread flush can overlap a submit-triggered
+                # inline flush.
                 for p in batch:
-                    self.batcher.submit(p.Xq)
-                results = self.batcher.drain()
-            except Exception as exc:                 # pragma: no cover
-                for p in batch:
-                    if p.future.set_running_or_notify_cancel():
-                        p.future.set_exception(exc)
-                raise
-            # drain() must return exactly one result per request handed
-            # to it; a mismatch means something enqueued on the inner
-            # batcher directly and a silent zip would scatter results to
-            # the wrong futures.
-            if len(results) != len(batch):           # pragma: no cover
-                exc = RuntimeError(
-                    f"flush expected {len(batch)} results, drained "
-                    f"{len(results)}: the inner MicroBatcher had foreign "
-                    f"pending requests")
-                for p in batch:
-                    if p.future.set_running_or_notify_cancel():
-                        p.future.set_exception(exc)
-                raise exc
-            complete_ts = self.clock()
-            # The pow-2 execution bucket this flush ran through: the
-            # coalesced width, bucketed by the inner batcher's policy
-            # (oversized batches chunk into max_bucket pieces, so the
-            # clamp is also the dominant executable). Keys the per-bucket
-            # latency breakdown.
-            width = sum(p.Xq.shape[1] for p in batch)
-            bucket = bucket_size(width, self.batcher.min_bucket,
-                                 self.batcher.max_bucket)
-            # LatencyStats mutation stays inside the flush lock: record()
-            # is read-modify-write on histogram counts, and a pump-thread
-            # flush can overlap a submit-triggered inline flush.
-            for p in batch:
-                self.latency.record(p.enqueue_ts, flush_ts, complete_ts,
-                                    queries=p.Xq.shape[1], bucket=bucket)
-        # A client may have cancel()ed its future while the request sat in
-        # the pending window; set_result on a cancelled future raises
-        # InvalidStateError and would strand every LATER future in the
-        # batch unresolved. set_running_or_notify_cancel() claims the
-        # future atomically (False = it was cancelled -> drop the result).
-        for p, res in zip(batch, results):
-            if p.future.set_running_or_notify_cancel():
-                p.future.set_result(res)
+                    self.latency.record(p.enqueue_ts, flush_ts,
+                                        complete_ts, queries=p.Xq.shape[1],
+                                        bucket=bucket)
+            # A client may have cancel()ed its future while the request
+            # sat in the pending window; set_result on a cancelled future
+            # raises InvalidStateError and would strand every LATER
+            # future in the batch unresolved.
+            # set_running_or_notify_cancel() claims the future atomically
+            # (False = it was cancelled -> drop the result).
+            for p, res in zip(batch, results):
+                if p.future.set_running_or_notify_cancel():
+                    p.future.set_result(res)
         return len(batch)
 
     # -- background pump -------------------------------------------------
@@ -332,7 +358,7 @@ class AsyncBatcher:
         if thread is not None:
             self._stop_event.set()
             thread.join()
-        return self.flush()
+        return self._flush("stop")
 
     def __enter__(self) -> "AsyncBatcher":
         return self.start()

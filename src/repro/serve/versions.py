@@ -52,6 +52,7 @@ import time
 from typing import List, Optional
 
 from repro.serve.artifact import FittedModel, load_model, save_model
+from repro.spans import span
 
 _VERSION_RE = re.compile(r"^v_(\d+)$")
 # A .tmp dir older than this is a crashed publish (a live one finishes in
@@ -114,22 +115,25 @@ class VersionStore:
         means taking the next number — never replacing a committed
         version another publisher already handed out.
         """
-        self.root.mkdir(parents=True, exist_ok=True)
-        vs = self.versions()
-        version = vs[-1] + 1 if vs else 1
-        tmp = self.root / f"v_{version}.{os.getpid()}.tmp"
-        if tmp.exists():
-            shutil.rmtree(tmp)
-        save_model(model, str(tmp))
-        while True:
-            try:
-                os.replace(tmp, self.root / f"v_{version}")
-                break
-            except OSError:
-                version += 1                    # target taken: next number
-        keep = keep if keep is not None else self.keep
-        if keep is not None:
-            self.gc(keep)
+        with span("store.publish") as publish_span:
+            self.root.mkdir(parents=True, exist_ok=True)
+            vs = self.versions()
+            version = vs[-1] + 1 if vs else 1
+            tmp = self.root / f"v_{version}.{os.getpid()}.tmp"
+            if tmp.exists():
+                shutil.rmtree(tmp)
+            save_model(model, str(tmp))
+            with span("store.commit"):
+                while True:
+                    try:
+                        os.replace(tmp, self.root / f"v_{version}")
+                        break
+                    except OSError:
+                        version += 1            # target taken: next number
+            publish_span.set_metadata(version=version)
+            keep = keep if keep is not None else self.keep
+            if keep is not None:
+                self.gc(keep)
         return version
 
     def load(self, version: Optional[int] = None) -> FittedModel:
@@ -185,6 +189,13 @@ class VersionStore:
         keep = keep if keep is not None else self.keep
         if keep is None or keep < 1:
             raise ValueError(f"gc needs keep >= 1, got {keep!r}")
+        with span("store.gc") as gc_span:
+            removed = self._gc(keep)
+            gc_span.set_metadata(removed=len(removed))
+        return removed
+
+    def _gc(self, keep: int) -> List[int]:
+        """gc() without the span: the sweep itself."""
         removed = []
         for v in self.versions()[:-keep]:
             if self.pins(v):                     # a worker still serves it

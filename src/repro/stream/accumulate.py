@@ -43,6 +43,7 @@ from repro.core.kernels_fn import KernelFn
 from repro.core.sketch import (GaussianSketch, LowRankEig, SRHT,
                                make_gaussian, make_srht, one_pass_core,
                                srht_apply_t, srht_rows_at)
+from repro.spans import span
 
 Sketch = Union[SRHT, GaussianSketch]
 
@@ -301,10 +302,11 @@ class SketchAccumulator:
                    else jnp.concatenate([self._X, X_chunk], axis=1))
         if self._engine is not None:
             self._engine.ingest(X_chunk)
-        while self.n_added - self.n_applied >= self.block:
-            self.W, self.row_norms2 = self._apply(
-                self.W, self.row_norms2, self.n_applied, self.block)
-            self.n_applied += self.block
+        with span("fit.accumulate"):
+            while self.n_added - self.n_applied >= self.block:
+                self.W, self.row_norms2 = self._apply(
+                    self.W, self.row_norms2, self.n_applied, self.block)
+                self.n_applied += self.block
         return self
 
     def _apply(self, W, row_norms2, q, b):
@@ -314,17 +316,23 @@ class SketchAccumulator:
         Dispatch: mesh policy -> the sharded engine (bit-identical to
         the canonical path on one device); fit_fused policy -> the
         single-host Pallas fit_sketch path (fp-tolerance parity, like
-        fused serving); otherwise the canonical eager update below."""
-        if self._engine is not None:
-            return self._engine.apply(W, row_norms2, q, b)
-        if self._fit_fused:
-            return self._apply_fused(W, row_norms2, q, b)
-        # Every shape below is fixed for the whole pass (m columns added,
-        # capacity rows) and the offset q is a device scalar, so each
-        # eager op compiles once per fit, not once per block. The
-        # border is computed against all m columns with the rows past
-        # q+b zeroed; each entry is the value kappa(X[:, :q+b], C)
-        # would give, and the zero rows add nothing to any reduction.
+        fused serving); otherwise the canonical eager update."""
+        with span("fit.block", q=int(q), b=int(b)):
+            if self._engine is not None:
+                return self._engine.apply(W, row_norms2, q, b)
+            if self._fit_fused:
+                return self._apply_fused(W, row_norms2, q, b)
+            return self._apply_eager(W, row_norms2, q, b)
+
+    def _apply_eager(self, W, row_norms2, q, b):
+        """The canonical block update, in eager ops.
+
+        Every shape is fixed for the whole pass (m columns added,
+        capacity rows) and the offset q is a device scalar, so each
+        eager op compiles once per fit, not once per block. The border
+        is computed against all m columns with the rows past q+b
+        zeroed; each entry is the value kappa(X[:, :q+b], C) would give,
+        and the zero rows add nothing to any reduction."""
         X = self._X
         m = int(X.shape[1])
         qd = jnp.asarray(q, jnp.int32)
@@ -405,33 +413,34 @@ class SketchAccumulator:
         `last_approx_err` (sqrt(1 - sum(eigvals^2) / ||K||_F^2), the
         free residual estimate the drift monitor thresholds on).
         """
-        r = self.r if r is None else int(r)
-        W, rn, n_eff = self._effective_state()
-        if n_eff < 1:
-            raise RuntimeError("no data accumulated; call add() first")
-        Wn = W[:n_eff]
-        if self.truncate_basis:
-            U, S, Vt = jnp.linalg.svd(Wn, full_matrices=False)
-            Wn = (U[:, :r] * S[None, :r]) @ Vt[:r]
-        if isinstance(self.sketch, SRHT):
-            if n_eff == self.capacity:
-                def omega_t_q(Q):
-                    return srht_apply_t(self.sketch, Q, self.fwht_fn)
+        with span("fit.eig"):
+            r = self.r if r is None else int(r)
+            W, rn, n_eff = self._effective_state()
+            if n_eff < 1:
+                raise RuntimeError("no data accumulated; call add() first")
+            Wn = W[:n_eff]
+            if self.truncate_basis:
+                U, S, Vt = jnp.linalg.svd(Wn, full_matrices=False)
+                Wn = (U[:, :r] * S[None, :r]) @ Vt[:r]
+            if isinstance(self.sketch, SRHT):
+                if n_eff == self.capacity:
+                    def omega_t_q(Q):
+                        return srht_apply_t(self.sketch, Q, self.fwht_fn)
+                else:
+                    def omega_t_q(Q):
+                        Qp = jnp.zeros((self.capacity, Q.shape[1]),
+                                       Q.dtype).at[:n_eff].set(Q)
+                        return srht_apply_t(self.sketch, Qp, self.fwht_fn)
             else:
                 def omega_t_q(Q):
-                    Qp = jnp.zeros((self.capacity, Q.shape[1]),
-                                   Q.dtype).at[:n_eff].set(Q)
-                    return srht_apply_t(self.sketch, Qp, self.fwht_fn)
-        else:
-            def omega_t_q(Q):
-                return self.sketch.omega[:n_eff].T @ Q
-        out = one_pass_core(Wn, omega_t_q, r)
-        fro2 = float(jnp.sum(rn))
-        tail2 = max(fro2 - float(jnp.sum(out.eigvals ** 2)), 0.0)
-        self.last_fro2 = fro2
-        self.last_approx_err = (tail2 / fro2) ** 0.5 if fro2 > 0 else 0.0
-        self.reeigs += 1
-        return out
+                    return self.sketch.omega[:n_eff].T @ Q
+            out = one_pass_core(Wn, omega_t_q, r)
+            fro2 = float(jnp.sum(rn))
+            tail2 = max(fro2 - float(jnp.sum(out.eigvals ** 2)), 0.0)
+            self.last_fro2 = fro2
+            self.last_approx_err = (tail2 / fro2) ** 0.5 if fro2 > 0 else 0.0
+            self.reeigs += 1
+            return out
 
     # -- persistence -----------------------------------------------------
 
