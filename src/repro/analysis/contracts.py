@@ -84,6 +84,7 @@ class _Capture:
     out_specs: tuple
     arg_shapes: tuple            # ((shape, itemsize), ...) matching in_specs
     out_shapes: tuple            # ((shape, itemsize), ...) matching out_specs
+    prefetch: tuple = ()         # scalar-prefetch operands, as passed
 
 
 def _as_tuple(x) -> tuple:
@@ -99,7 +100,10 @@ def capture_pallas_calls(thunk: Callable[[], object]) -> List[_Capture]:
 
     The recorder never executes the kernel body — it logs the call's
     grid/specs/shapes and returns zeros of out_shape, which is enough
-    for the wrappers' pad/slice plumbing to trace through.
+    for the wrappers' pad/slice plumbing to trace through. A
+    `grid_spec=` (e.g. pltpu.PrefetchScalarGridSpec) supplies grid and
+    specs; its leading `num_scalar_prefetch` operands are recorded as
+    `prefetch` (SMEM scalars, no HBM blocks) and not as array operands.
     """
     import jax
     import jax.numpy as jnp
@@ -109,8 +113,13 @@ def capture_pallas_calls(thunk: Callable[[], object]) -> List[_Capture]:
     real = pallas.pallas_call
 
     def fake(kernel, *, out_shape, grid=None, in_specs=None,
-             out_specs=None, **unused_kw):
+             out_specs=None, grid_spec=None, **unused_kw):
         outs = _as_tuple(out_shape)
+        n_pre = 0
+        if grid_spec is not None:
+            grid, in_specs = grid_spec.grid, grid_spec.in_specs
+            out_specs = grid_spec.out_specs
+            n_pre = getattr(grid_spec, "num_scalar_prefetch", 0)
 
         def runner(*args):
             caps.append(_Capture(
@@ -118,9 +127,10 @@ def capture_pallas_calls(thunk: Callable[[], object]) -> List[_Capture]:
                 in_specs=_as_tuple(in_specs),
                 out_specs=_as_tuple(out_specs),
                 arg_shapes=tuple((tuple(a.shape), jnp.dtype(a.dtype).itemsize)
-                                 for a in args),
+                                 for a in args[n_pre:]),
                 out_shapes=tuple((tuple(s.shape), jnp.dtype(s.dtype).itemsize)
                                  for s in outs),
+                prefetch=tuple(args[:n_pre]),
             ))
             zeros = [jnp.zeros(s.shape, s.dtype) for s in outs]
             if isinstance(out_shape, (tuple, list)):
@@ -138,7 +148,12 @@ def capture_pallas_calls(thunk: Callable[[], object]) -> List[_Capture]:
 
 
 def derive_call(cap: _Capture) -> CallReport:
-    """BlockSpec-derived HBM/VMEM totals for one captured call."""
+    """BlockSpec-derived HBM/VMEM totals for one captured call.
+
+    Each index map is called with the grid point followed by the
+    scalar-prefetch values, which must then be concrete (a wrapper
+    passes a traced bound only when its caller asks for one, and the
+    registered cases do not)."""
     grid = tuple(int(g) for g in cap.grid)
     n_points = math.prod(grid) if grid else 1
     if n_points > _MAX_GRID_POINTS:
@@ -150,7 +165,9 @@ def derive_call(cap: _Capture) -> CallReport:
 
     def add(name: str, spec, itemsize: int) -> None:
         block = tuple(int(d) for d in spec.block_shape)
-        coords = {_as_tuple(spec.index_map(*pt)) for pt in points}
+        coords = {tuple(int(c) for c in
+                        _as_tuple(spec.index_map(*pt, *cap.prefetch)))
+                  for pt in points}
         block_bytes = math.prod(block) * itemsize
         operands.append(OperandReport(
             name=name, block_shape=block, block_bytes=block_bytes,

@@ -207,6 +207,8 @@ class ShardedFitEngine:
                 O_l = jnp.where(valid[:, None], O_l, 0.0)
                 V = jnp.zeros((8, L), jnp.float32).at[0].set(
                     valid.astype(jnp.float32))
+                # Sweeps the whole local slab (no `border`); the local
+                # bound would be clip(q + b - dev * L, 0, L).
                 accp, delta, rn_rows, rn_cols = fit_sketch_pallas(
                     xl, O_l, c, cross, V, kind=kind, gamma=gamma,
                     degree=degree, interpret=interp)

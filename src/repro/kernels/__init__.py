@@ -11,7 +11,8 @@ extend_embed/  fused gram->projection serving stripe: the (n, w) kernel
 fit_sketch/    fused gram->sketch-accumulate training stripe: each
                (m, b) kernel block is contracted into the (b, r') sketch
                rows, cross-term and Frobenius ledgers in one pass with
-               the sketch accumulator VMEM-resident (stream/accumulate)
+               the sketch accumulator VMEM-resident, visiting only the
+               row tiles of the block's border (stream/accumulate)
 
 Each subpackage ships <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 public wrapper, interpret=True on CPU) and ref.py (pure-jnp oracle used by

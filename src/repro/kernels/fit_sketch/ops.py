@@ -5,6 +5,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels.fit_sketch.fit_sketch import fit_sketch_call
 from repro.kernels.fit_sketch.ref import fit_sketch_ref
@@ -43,6 +44,16 @@ def padded_shapes(m: int, b: int, rp: int, row_tile: int = 256
     return row_tile, m_pad, b_pad, rp_pad
 
 
+def border_tiles(m: int, border: int, row_tile: int = 256
+                 ) -> tuple[int, int]:
+    """(tiles visited, tiles in the grid) of a call over m rows bounded
+    to `border` rows: the host-side count of what fit_sketch_pallas's
+    `border` makes the kernel sweep."""
+    row_tile, m_pad, _, _ = padded_shapes(m, 1, 1, row_tile)
+    tiles = m_pad // row_tile
+    return min(max(-(-border // row_tile), 1), tiles), tiles
+
+
 def memory_contract(p: int, m: int, b: int, rp: int, row_tile: int = 256
                     ) -> dict:
     """Declared HBM byte model for one fused fit-block call.
@@ -53,6 +64,10 @@ def memory_contract(p: int, m: int, b: int, rp: int, row_tile: int = 256
     footprints. serve/bench.py reports THESE numbers and
     `repro.analysis` cross-checks them against the kernel's BlockSpecs
     at every registered parity case (rule C001).
+
+    This is the full-sweep upper bound (no `border`). A call bounded to
+    `border` rows moves X, Omega, V, delta and the row norms for its
+    nt = border_tiles(m, border)[0] leading tiles only.
     """
     row_tile, m_pad, b_pad, rp_pad = padded_shapes(m, b, rp, row_tile)
     hbm = 4.0 * (p * m_pad             # X (p, m_pad) streamed
@@ -74,7 +89,8 @@ def fit_sketch_pallas(X: jnp.ndarray, Omega: jnp.ndarray, C: jnp.ndarray,
                       Ocross: jnp.ndarray, V: jnp.ndarray | None = None,
                       kind: str = "polynomial", gamma: float = 0.0,
                       degree: int = 2, row_tile: int = 256,
-                      interpret: bool | None = None):
+                      interpret: bool | None = None,
+                      border: jnp.ndarray | int | None = None):
     """Fused fit-block contractions of K = kappa(X, C), one executable.
 
     X (p, m) samples as columns, Omega (m, r') sketch rows (callers zero
@@ -87,12 +103,27 @@ def fit_sketch_pallas(X: jnp.ndarray, Omega: jnp.ndarray, C: jnp.ndarray,
     lanes; padded Omega/Ocross rows are zero and padded V columns are
     zero, so every padded contribution is annihilated (exact, not
     approximate), and padded output rows/columns are sliced off.
+
+    border (traced int, optional): only the leading `border` rows of X
+    matter — a fit block's [0, q+b). The kernel then visits the row
+    tiles that hold them and no others; rows past the border must
+    already be zero in Omega and V (as above), so new_rows and rn_cols
+    are bit-identical to the full sweep. delta and rn_rows are computed
+    for every row of those tiles; rows of later tiles are left
+    unwritten (arbitrary values), so a caller reads them only below
+    the border. None sweeps all m rows.
     """
     interp = _is_cpu() if interpret is None else interpret
     m = X.shape[1]
     b = C.shape[1]
     rp = Omega.shape[1]
-    row_tile, _, _, _ = padded_shapes(m, b, rp, row_tile)
+    row_tile, m_pad, _, _ = padded_shapes(m, b, rp, row_tile)
+    tiles = m_pad // row_tile
+    if border is None:
+        nt = np.full((1,), tiles, np.int32)
+    else:
+        nt = jnp.clip(-(-jnp.asarray(border, jnp.int32) // row_tile), 1,
+                      tiles).reshape(1)
     if V is None:
         V = jnp.zeros((8, m), jnp.float32).at[0].set(1.0)
     Xp = _pad_to(X, 1, row_tile)
@@ -100,7 +131,7 @@ def fit_sketch_pallas(X: jnp.ndarray, Omega: jnp.ndarray, C: jnp.ndarray,
     Cp = _pad_to(C, 1, 128)
     Ocrp = _pad_to(_pad_to(Ocross, 0, 128), 1, 128)
     Vp = _pad_to(V, 1, row_tile)
-    acc, delta, rnr, rnc = fit_sketch_call(Xp, Op, Cp, Ocrp, Vp, kind,
+    acc, delta, rnr, rnc = fit_sketch_call(nt, Xp, Op, Cp, Ocrp, Vp, kind,
                                            gamma, degree, b, row_tile,
                                            interp)
     return acc[:b, :rp], delta[:m, :rp], rnr[:m, 0], rnc[0, :b]

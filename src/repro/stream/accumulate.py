@@ -55,9 +55,10 @@ def _fused_block_update(X, W, row_norms2, aux, rows, q, *, b: int,
                         interpret: bool):
     """Fold columns [q, q+b) of X (p, m) into (W, row_norms2) through the
     fit_sketch kernel. The offset q is traced, so every block of one
-    width shares this executable: the kernel sweeps all m columns with
-    the Omega rows and validity mask of rows >= q+b zeroed, which is the
-    kernel's own exact-padding contract. aux is the SRHT sign diagonal
+    width shares this executable: the Omega rows and validity mask of
+    rows >= q+b are zeroed, which is the kernel's own exact-padding
+    contract, and the kernel is bounded to the border q+b, so it visits
+    only the row tiles that hold [0, q+b). aux is the SRHT sign diagonal
     (with `rows` its sampled rows) or, when rows is None, the dense
     Gaussian Omega."""
     from repro.kernels.fit_sketch.ops import fit_sketch_pallas
@@ -78,7 +79,7 @@ def _fused_block_update(X, W, row_norms2, aux, rows, q, *, b: int,
     V = jnp.zeros((8, m), jnp.float32).at[0].set(valid.astype(jnp.float32))
     new_rows, delta, rn_rows, rn_cols = fit_sketch_pallas(
         X, Omega, C, cross, V, kind=kind, gamma=gamma, degree=degree,
-        interpret=interpret)
+        interpret=interpret, border=q + b)
     applied = gids < q
     Wm = jnp.where(applied[:, None], W[:m] + delta, W[:m])
     rnm = jnp.where(applied, row_norms2[:m] + rn_rows, row_norms2[:m])
@@ -316,8 +317,15 @@ class SketchAccumulator:
         Dispatch: mesh policy -> the sharded engine (bit-identical to
         the canonical path on one device); fit_fused policy -> the
         single-host Pallas fit_sketch path (fp-tolerance parity, like
-        fused serving); otherwise the canonical eager update."""
-        with span("fit.block", q=int(q), b=int(b)):
+        fused serving); otherwise the canonical eager update. On the
+        fused path the span also carries `tiles`, the row tiles the
+        kernel visits, and `tiles_total`, the tiles of its grid."""
+        args = {}
+        if self._engine is None and self._fit_fused:
+            from repro.kernels.fit_sketch.ops import border_tiles
+            args["tiles"], args["tiles_total"] = border_tiles(
+                self.n_added, int(q) + int(b))
+        with span("fit.block", q=int(q), b=int(b), **args):
             if self._engine is not None:
                 return self._engine.apply(W, row_norms2, q, b)
             if self._fit_fused:

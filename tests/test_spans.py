@@ -10,7 +10,8 @@ import pytest
 from repro import spans
 from repro.api import KernelKMeans
 from repro.data import blob_ring
-from repro.serve import AsyncBatcher, MicroBatcher, VersionStore
+from repro.serve import (AsyncBatcher, ComputePolicy, MicroBatcher,
+                         VersionStore)
 
 N, P, R, K, BLOCK = 250, 2, 2, 2, 64
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -193,6 +194,20 @@ def test_fit_and_publish_spans(tmp_path, data):
         assert 0 < meta["args"]["bytes"] < leaves["args"]["bytes"]
         removed.append(kids[-1]["args"]["removed"])
     assert removed == [0, 1]                   # keep-last-1 drops v_1
+
+
+def test_fused_fit_blocks_carry_their_tile_counts(tmp_path, data):
+    """On the fit_sketch path each fit.block says how many of the
+    kernel's row tiles it visits: those holding [0, q+b), of 1 here
+    (250 columns fit one 256-row tile)."""
+    est = KernelKMeans(k=K, r=R, kernel="rbf", kernel_params={"gamma": 0.5},
+                       backend_params={"oversampling": 10}, block=BLOCK,
+                       policy=ComputePolicy(fit_fused=True, interpret=True))
+    _, found = _traced(tmp_path, lambda: est.fit(
+        data, key=jax.random.PRNGKey(1)))
+    assert [s["args"] for s in _named(found, "fit.block")] == [
+        {"q": q, "b": b, "tiles": 1, "tiles_total": 1}
+        for q, b in ((0, 64), (64, 64), (128, 64), (192, 58))]
 
 
 def test_prefix_is_the_one_the_benchmark_reads(monkeypatch):

@@ -80,6 +80,12 @@ CASES = {
             X, O, C, Oc, V, kind="rbf", gamma=GAMMA, interpret=False),
         [_f32(P, N), _f32(N, 20), _f32(P, 512), _f32(512, 20),
          _f32(8, N)]),
+    "fit_sketch_border": (
+        lambda X, O, C, Oc, V, border: fit_sketch_pallas(
+            X, O, C, Oc, V, kind="rbf", gamma=GAMMA, interpret=False,
+            border=border),
+        [_f32(P, N), _f32(N, 20), _f32(P, 512), _f32(512, 20),
+         _f32(8, N), ((), jnp.int32)]),
     "fit_block_update": (
         lambda X, W, rn, signs, rows, q: _fused_block_update(
             X, W, rn, signs, rows, q, b=512, n_pad=1 << 17, kind="rbf",
