@@ -3,6 +3,8 @@
 No chip is needed: the TPU compiler is installed with JAX and compiles
 for a topology that is described, not attached. Each case lowers a
 kernel at the widths of the MNIST-scale deployment (70,000 x 784, RBF)
+or, for the fit path, of Covertype at its published n (581,012 x 54,
+r' = 17, the SRHT padded to 2^20, full blocks of 512 and a tail of 404)
 and asserts that Mosaic accepted it: interpret mode cannot see a block
 that does not match the device layout, or a kernel that asks for more
 than the 16 MiB of scoped VMEM. Nothing runs, so this says nothing about
@@ -28,6 +30,7 @@ from repro.stream.accumulate import _fused_block_update
 
 N, P = 70_000, 784           # MNIST's shape
 GAMMA = 1e-3
+CN, CP, CR = 581_012, 54, 17  # Covertype at its published n; r' = 7 + 10
 
 
 @pytest.fixture(scope="module")
@@ -63,6 +66,24 @@ def _f32(*shape):
     return (shape, jnp.float32)
 
 
+def _fit_sketch_border(p, m, rp, b=512):
+    """fit_sketch bounded to a traced border, over m rows of p features."""
+    return (lambda X, O, C, Oc, V, border: fit_sketch_pallas(
+                X, O, C, Oc, V, kind="rbf", gamma=GAMMA, interpret=False,
+                border=border),
+            [_f32(p, m), _f32(m, rp), _f32(p, b), _f32(b, rp), _f32(8, m),
+             ((), jnp.int32)])
+
+
+def _fit_block_update(p, m, rp, b, n_pad):
+    """One fit block of width b over m columns, SRHT padded to n_pad."""
+    return (lambda X, W, rn, signs, rows, q: _fused_block_update(
+                X, W, rn, signs, rows, q, b=b, n_pad=n_pad, kind="rbf",
+                gamma=GAMMA, degree=2, interpret=False),
+            [_f32(p, m), _f32(m, rp), _f32(m), _f32(n_pad),
+             ((rp,), jnp.int32), ((), jnp.int32)])
+
+
 # name -> (function of arrays, argument (shape, dtype)s)
 CASES = {
     "kmeans_assign_n64": (
@@ -80,18 +101,13 @@ CASES = {
             X, O, C, Oc, V, kind="rbf", gamma=GAMMA, interpret=False),
         [_f32(P, N), _f32(N, 20), _f32(P, 512), _f32(512, 20),
          _f32(8, N)]),
-    "fit_sketch_border": (
-        lambda X, O, C, Oc, V, border: fit_sketch_pallas(
-            X, O, C, Oc, V, kind="rbf", gamma=GAMMA, interpret=False,
-            border=border),
-        [_f32(P, N), _f32(N, 20), _f32(P, 512), _f32(512, 20),
-         _f32(8, N), ((), jnp.int32)]),
-    "fit_block_update": (
-        lambda X, W, rn, signs, rows, q: _fused_block_update(
-            X, W, rn, signs, rows, q, b=512, n_pad=1 << 17, kind="rbf",
-            gamma=GAMMA, degree=2, interpret=False),
-        [_f32(P, N), _f32(N, 20), _f32(N), _f32(1 << 17),
-         ((20,), jnp.int32), ((), jnp.int32)]),
+    "fit_sketch_border": _fit_sketch_border(P, N, 20),
+    "fit_block_update": _fit_block_update(P, N, 20, 512, 1 << 17),
+    "fit_sketch_border_covtype_full": _fit_sketch_border(CP, CN, CR),
+    "fit_block_update_covtype_full_b512": _fit_block_update(
+        CP, CN, CR, 512, 1 << 20),
+    "fit_block_update_covtype_full_b404": _fit_block_update(
+        CP, CN, CR, CN % 512, 1 << 20),
     "gram": (
         lambda X, Xb: gram_stripe_pallas(X, Xb, kind="rbf", gamma=GAMMA,
                                          interpret=False),
