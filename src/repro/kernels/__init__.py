@@ -12,7 +12,8 @@ fit_sketch/    fused gram->sketch-accumulate training stripe: each
                (m, b) kernel block is contracted into the (b, r') sketch
                rows, cross-term and Frobenius ledgers in one pass with
                the sketch accumulator VMEM-resident, visiting only the
-               row tiles of the block's border (stream/accumulate)
+               row tiles of the block's border; the fit's entry updates
+               the sketch state in place (stream/accumulate)
 
 Each subpackage ships <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 public wrapper, interpret=True on CPU) and ref.py (pure-jnp oracle used by

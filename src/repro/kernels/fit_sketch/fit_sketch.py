@@ -35,6 +35,19 @@ by V (rn_col) or sliced/masked by the caller (delta, rn_row); garbage
 gram COLUMNS (padded C columns) are annihilated by zero rows of Ocross
 (delta), excluded by the static b_real column mask (rn_row) or sliced by
 the caller (new_rows, rn_col).
+
+Two entry points share the tile body (_gram_tile, _fold_tile):
+
+* fit_sketch_call, functional, as above: delta and rn_row come back for
+  every row (the sharded fit engine sums them across the mesh).
+* fit_block_call, in place: the scalar-prefetch operand is the block's
+  offset q, the border q + b_real and nt are derived from it, and the
+  gram tile's rows at or past the border are zeroed in the kernel, so
+  Omega needs no per-block zeroing and there is no V. The sketch state
+  W (S, r') and its row norms (8, S) (row 0 live) are inputs aliased to
+  outputs: each visited tile writes W + delta to its rows < q and the
+  row norms likewise, and leaves every other row as it was. Tiles past
+  the border are neither read nor written, and delta never leaves VMEM.
 """
 from __future__ import annotations
 
@@ -46,6 +59,42 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.registry import KERNEL_PRECISION
+
+
+def _gram_tile(xi, xb, *, kind: str, gamma: float, degree: int):
+    """The (bm, w) gram tile kappa(X_i, C) of one row tile."""
+    z = jax.lax.dot_general(xi, xb, (((0,), (0,)), ((), ())),
+                            precision=KERNEL_PRECISION,
+                            preferred_element_type=jnp.float32)
+    if kind == "polynomial":
+        return (z + gamma) ** degree
+    if kind == "rbf":
+        xn = jnp.sum(xi * xi, axis=0)[:, None]
+        yn = jnp.sum(xb * xb, axis=0)[None, :]
+        return jnp.exp(-gamma * jnp.maximum(xn + yn - 2.0 * z, 0.0))
+    return z  # linear
+
+
+def _fold_tile(k, k2, oi, ocr, e0, acc_ref, rnc_ref, *, b_real: int):
+    """Fold one gram tile k and its square k2 into the resident
+    accumulators; return the tile's cross-term rows delta = k Ocross
+    (bm, rp) and its row norms (bm, 1). e0 (8, bm) weighs the rows in
+    the column norms (row 0)."""
+    acc_part = jax.lax.dot_general(k, oi, (((0,), (0,)), ((), ())),
+                                   precision=KERNEL_PRECISION,
+                                   preferred_element_type=jnp.float32)
+    delta = jax.lax.dot_general(k, ocr, (((1,), (0,)), ((), ())),
+                                precision=KERNEL_PRECISION,
+                                preferred_element_type=jnp.float32)
+    colmask = jax.lax.broadcasted_iota(jnp.int32, (1, k.shape[1]),
+                                       1) < b_real
+    rnr = jnp.sum(jnp.where(colmask, k2, 0.0), axis=1, keepdims=True)
+    rnc_part = jax.lax.dot_general(e0, k2, (((1,), (0,)), ((), ())),
+                                   precision=KERNEL_PRECISION,
+                                   preferred_element_type=jnp.float32)
+    acc_ref[...] += acc_part.astype(acc_ref.dtype)   # (w, rp) resident
+    rnc_ref[...] += rnc_part.astype(rnc_ref.dtype)   # (8, w) resident
+    return delta, rnr
 
 
 def _fit_sketch_kernel(nt_ref, xi_ref, oi_ref, xb_ref, ocr_ref, vi_ref,
@@ -60,37 +109,12 @@ def _fit_sketch_kernel(nt_ref, xi_ref, oi_ref, xb_ref, ocr_ref, vi_ref,
 
     @pl.when(i < nt_ref[0])
     def _():
-        xi = xi_ref[...]                    # (p, bm)   X row tile
-        xb = xb_ref[...]                    # (p, w)    block columns C
-        z = jax.lax.dot_general(xi, xb, (((0,), (0,)), ((), ())),
-                                precision=KERNEL_PRECISION,
-                                preferred_element_type=jnp.float32)
-        if kind == "polynomial":
-            k = (z + gamma) ** degree
-        elif kind == "rbf":
-            xn = jnp.sum(xi * xi, axis=0)[:, None]
-            yn = jnp.sum(xb * xb, axis=0)[None, :]
-            k = jnp.exp(-gamma * jnp.maximum(xn + yn - 2.0 * z, 0.0))
-        else:  # linear
-            k = z
-        oi = oi_ref[...]                    # (bm, rp)  sketch rows of tile
-        acc_part = jax.lax.dot_general(k, oi, (((0,), (0,)), ((), ())),
-                                       precision=KERNEL_PRECISION,
-                                       preferred_element_type=jnp.float32)
-        ocr = ocr_ref[...]                  # (w, rp)   sketch rows of block
-        delta = jax.lax.dot_general(k, ocr, (((1,), (0,)), ((), ())),
-                                    precision=KERNEL_PRECISION,
-                                    preferred_element_type=jnp.float32)
-        k2 = k * k
-        colmask = jax.lax.broadcasted_iota(jnp.int32, (1, k.shape[1]),
-                                           1) < b_real
-        rnr = jnp.sum(jnp.where(colmask, k2, 0.0), axis=1, keepdims=True)
-        vi = vi_ref[...]                    # (8, bm)   row 0 = validity
-        rnc_part = jax.lax.dot_general(vi, k2, (((1,), (0,)), ((), ())),
-                                       precision=KERNEL_PRECISION,
-                                       preferred_element_type=jnp.float32)
-        acc_ref[...] += acc_part.astype(acc_ref.dtype)   # (w, rp) resident
-        rnc_ref[...] += rnc_part.astype(rnc_ref.dtype)   # (8, w) resident
+        k = _gram_tile(xi_ref[...], xb_ref[...], kind=kind, gamma=gamma,
+                       degree=degree)
+        # vi (8, bm): row 0 = validity
+        delta, rnr = _fold_tile(k, k * k, oi_ref[...], ocr_ref[...],
+                                vi_ref[...], acc_ref, rnc_ref,
+                                b_real=b_real)
         dl_ref[...] = delta.astype(dl_ref.dtype)         # (bm, rp) per tile
         rnr_ref[...] = jnp.broadcast_to(rnr, rnr_ref.shape).astype(
             rnr_ref.dtype)                               # (bm, 128) per tile
@@ -146,3 +170,100 @@ def fit_sketch_call(nt: jnp.ndarray, X: jnp.ndarray, Omega: jnp.ndarray,
         ),
         interpret=interpret,
     )(nt, X, Omega, C, Ocross, V)
+
+
+def _fit_block_kernel(q_ref, xi_ref, oi_ref, xb_ref, ocr_ref, wi_ref,
+                      rni_ref, acc_ref, wo_ref, rno_ref, rnc_ref, *,
+                      kind: str, gamma: float, degree: int, b_real: int):
+    i = pl.program_id(0)
+    bm = xi_ref.shape[1]
+    q = q_ref[0]
+    border = q + b_real
+
+    @pl.when(i == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        rnc_ref[...] = jnp.zeros_like(rnc_ref)
+
+    @pl.when(i * bm < border)
+    def _():
+        k = _gram_tile(xi_ref[...], xb_ref[...], kind=kind, gamma=gamma,
+                       degree=degree)
+        # Rows at or past the border (and a partial last tile's garbage,
+        # NaN included) become exact zeros: they add nothing below. k*k
+        # is formed from the unmasked k and masked after, as the
+        # functional kernel forms it, so the two agree bit for bit.
+        row = i * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
+        live = row < border
+        e0 = (jax.lax.broadcasted_iota(jnp.int32, (8, bm), 0) == 0
+              ).astype(jnp.float32)
+        delta, rnr = _fold_tile(jnp.where(live, k, 0.0),
+                                jnp.where(live, k * k, 0.0), oi_ref[...],
+                                ocr_ref[...], e0, acc_ref, rnc_ref,
+                                b_real=b_real)
+        wi = wi_ref[...]                    # (bm, rp)  sketch state rows
+        wo_ref[...] = jnp.where(row < q, wi + delta, wi)
+        # The row norms as lanes: the state keeps them in row 0 of (8, m).
+        rnr_t = jnp.transpose(jnp.broadcast_to(rnr, (bm, 128)))[:8]
+        sub = jax.lax.broadcasted_iota(jnp.int32, (8, bm), 0)
+        col = i * bm + jax.lax.broadcasted_iota(jnp.int32, (8, bm), 1)
+        rni = rni_ref[...]                  # (8, bm)   row 0 live
+        rno_ref[...] = jnp.where((sub == 0) & (col < q), rni + rnr_t, rni)
+
+
+def fit_block_call(q: jnp.ndarray, X: jnp.ndarray, Omega: jnp.ndarray,
+                   C: jnp.ndarray, Ocross: jnp.ndarray, W: jnp.ndarray,
+                   rn: jnp.ndarray, kind: str, gamma: float, degree: int,
+                   b_real: int, row_tile: int, interpret: bool):
+    """One fit block [q, q + b_real) folded into the sketch state in
+    place; the contractions of fit_sketch_call, masked by the border.
+
+    q (1,) int32 = the block's first column; X (p, m), its last row tile
+    may be partial; Omega (m_pad, rp) the sketch rows, m_pad = cdiv(m,
+    row_tile) * row_tile; C (p, w) and Ocross (w, rp) the block's
+    columns and sketch rows, zero past b_real; W (S, rp) and rn (8, S)
+    the state, S >= m_pad, aliased to the outputs. Returns acc (w, rp)
+    = the new sketch rows, W with delta added to its rows < q, rn with
+    the row norms added to row 0's columns < q, and rn_col (8, w). Only
+    the tiles that hold [0, q + b_real) are read or written; every other
+    row of W and rn keeps its value.
+    """
+    p, m = X.shape
+    rp = Omega.shape[1]
+    w = C.shape[1]
+    tiles = -(-m // row_tile)
+
+    def row(i, q_ref):
+        return jnp.minimum(i, (q_ref[0] + b_real - 1) // row_tile)
+
+    return pl.pallas_call(
+        functools.partial(_fit_block_kernel, kind=kind, gamma=gamma,
+                          degree=degree, b_real=b_real),
+        out_shape=(
+            jax.ShapeDtypeStruct((w, rp), jnp.float32),
+            jax.ShapeDtypeStruct(W.shape, jnp.float32),
+            jax.ShapeDtypeStruct(rn.shape, jnp.float32),
+            jax.ShapeDtypeStruct((8, w), jnp.float32),
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(tiles,),
+            in_specs=[
+                pl.BlockSpec((p, row_tile), lambda i, q: (0, row(i, q))),
+                pl.BlockSpec((row_tile, rp), lambda i, q: (row(i, q), 0)),
+                pl.BlockSpec((p, w), lambda i, q: (0, 0)),
+                pl.BlockSpec((w, rp), lambda i, q: (0, 0)),
+                pl.BlockSpec((row_tile, rp), lambda i, q: (row(i, q), 0)),
+                pl.BlockSpec((8, row_tile), lambda i, q: (0, row(i, q))),
+            ],
+            out_specs=(
+                pl.BlockSpec((w, rp), lambda i, q: (0, 0)),
+                pl.BlockSpec((row_tile, rp), lambda i, q: (row(i, q), 0)),
+                pl.BlockSpec((8, row_tile), lambda i, q: (0, row(i, q))),
+                pl.BlockSpec((8, w), lambda i, q: (0, 0)),
+            ),
+        ),
+        # Operand indices count the prefetched q: W is 5, rn is 6.
+        input_output_aliases={5: 1, 6: 2},
+        interpret=interpret,
+    )(q, X, Omega, C, Ocross, W, rn)

@@ -25,3 +25,23 @@ def fit_sketch_ref(X: jnp.ndarray, Omega: jnp.ndarray, C: jnp.ndarray,
     rn_rows = jnp.sum(K * K, axis=1)
     rn_cols = vm @ (K * K)
     return new_rows, delta, rn_rows, rn_cols
+
+
+def fit_sketch_inplace_ref(X: jnp.ndarray, Omega: jnp.ndarray,
+                           W: jnp.ndarray, rn: jnp.ndarray, q: int, b: int,
+                           kind: str = "polynomial", gamma: float = 0.0,
+                           degree: int = 2):
+    """One fit block [q, q+b) folded into the state (W, rn), in the
+    layout of fit_sketch_inplace: Omega (m_pad, rp) the sketch rows, W
+    (S, rp), rn (8, S) with the row norms in row 0. Returns (W, rn)
+    with W[:q] += K[:q] Omega[q:q+b], W[q:q+b] = K^T Omega[:q+b], and
+    the row and column sums of K*K likewise in rn[0], for K =
+    kappa(X[:, :q+b], X[:, q:q+b])."""
+    K = gram_stripe_ref(X[:, :q + b], X[:, q:q + b], kind=kind,
+                        gamma=gamma, degree=degree)
+    K2 = K * K
+    W = W.at[:q].add(K[:q] @ Omega[q:q + b])
+    W = W.at[q:q + b].set(K.T @ Omega[:q + b])
+    rn = rn.at[0, :q].add(jnp.sum(K2[:q], axis=1))
+    rn = rn.at[0, q:q + b].set(jnp.sum(K2, axis=0))
+    return W, rn

@@ -48,46 +48,44 @@ from repro.spans import span
 Sketch = Union[SRHT, GaussianSketch]
 
 
-@functools.partial(jax.jit, static_argnames=("b", "n_pad", "kind", "gamma",
-                                             "degree", "interpret"))
+@functools.partial(jax.jit, static_argnames=("b", "kind", "gamma",
+                                             "degree", "interpret"),
+                   donate_argnums=(1, 2))
 def _fused_block_update(X, W, row_norms2, aux, rows, q, *, b: int,
-                        n_pad: int, kind: str, gamma: float, degree: int,
+                        kind: str, gamma: float, degree: int,
                         interpret: bool):
-    """Fold columns [q, q+b) of X (p, m) into (W, row_norms2) through the
-    fit_sketch kernel. The offset q is traced, so every block of one
-    width shares this executable: the Omega rows and validity mask of
-    rows >= q+b are zeroed, which is the kernel's own exact-padding
-    contract, and the kernel is bounded to the border q+b, so it visits
-    only the row tiles that hold [0, q+b). aux is the SRHT sign diagonal
-    (with `rows` its sampled rows) or, when rows is None, the dense
-    Gaussian Omega."""
-    from repro.kernels.fit_sketch.ops import fit_sketch_pallas
+    """Fold columns [q, q+b) of X (p, m) into the state (W, row_norms2)
+    through the in-place fit_sketch kernel; returns the updated pair.
 
-    m = X.shape[1]
-    gids = jnp.arange(m, dtype=jnp.int32)
-    bids = q + jnp.arange(b, dtype=jnp.int32)
-    valid = gids < q + b
-    C = jax.lax.dynamic_slice_in_dim(X, q, b, axis=1)
+    W and row_norms2 are the state in the kernel's layout
+    (to_kernel_state) and are donated: the kernel updates them in place,
+    reading and writing only the row tiles of the border [0, q+b). aux
+    is the pass's sketch rows as kernel_rows() lays them out, prepared
+    once per pass; rows is None (the Omega rows no longer come from the
+    SRHT's sampled rows here). The offset q is traced, so every block of
+    one width shares this executable, and no operation outside the
+    kernel touches O(m) rows.
+    """
+    from repro.kernels.fit_sketch.ops import fit_sketch_inplace_jit
+
+    del rows
+    return fit_sketch_inplace_jit(X, aux, W, row_norms2, q, b=b, kind=kind,
+                                  gamma=gamma, degree=degree,
+                                  interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("m", "n_pad"))
+def _prepared_rows(aux, rows, *, m: int, n_pad: int):
+    """The sketch rows Omega[:m] of a pass over m columns, in the layout
+    the in-place fit_sketch kernel reads: the SRHT's rows materialized
+    from its signs `aux` and sampled `rows`, or, when rows is None, the
+    dense Gaussian Omega `aux`."""
+    from repro.kernels.fit_sketch.ops import kernel_rows
+
     if rows is None:
-        Omega = aux[:m]
-        cross = jax.lax.dynamic_slice_in_dim(aux, q, b, axis=0)
-    else:
-        Omega = srht_rows_at(gids, aux[:m], rows, n_pad)
-        cross = srht_rows_at(bids, jax.lax.dynamic_slice(aux, (q,), (b,)),
-                             rows, n_pad)
-    Omega = jnp.where(valid[:, None], Omega, 0.0)
-    V = jnp.zeros((8, m), jnp.float32).at[0].set(valid.astype(jnp.float32))
-    new_rows, delta, rn_rows, rn_cols = fit_sketch_pallas(
-        X, Omega, C, cross, V, kind=kind, gamma=gamma, degree=degree,
-        interpret=interpret, border=q + b)
-    applied = gids < q
-    Wm = jnp.where(applied[:, None], W[:m] + delta, W[:m])
-    rnm = jnp.where(applied, row_norms2[:m] + rn_rows, row_norms2[:m])
-    W = jax.lax.dynamic_update_slice(W, Wm, (0, 0))
-    row_norms2 = jax.lax.dynamic_update_slice(row_norms2, rnm, (0,))
-    W = jax.lax.dynamic_update_slice(W, new_rows, (q, 0))
-    row_norms2 = jax.lax.dynamic_update_slice(row_norms2, rn_cols, (q,))
-    return W, row_norms2
+        return kernel_rows(aux[:m])
+    return kernel_rows(srht_rows_at(jnp.arange(m, dtype=jnp.int32),
+                                    aux[:m], rows, n_pad))
 
 
 class SketchAccumulator:
@@ -105,7 +103,10 @@ class SketchAccumulator:
                  block update through the mesh-sharded fit engine
                  (distributed/fit.py, bit-identical on one device);
                  policy.fit_fused routes it through the fused
-                 fit_sketch Pallas kernel (fp-tolerance parity).
+                 fit_sketch Pallas kernel (fp-tolerance parity), which
+                 updates the state in place: self.W / self.row_norms2
+                 then hold it in the kernel's layout
+                 (kernels/fit_sketch/ops.py:to_kernel_state).
     kernel_statics: (kind, gamma, degree) for the fused kernel; required
                  whenever fit_fused resolves on.
 
@@ -131,9 +132,7 @@ class SketchAccumulator:
             sketch = make_gaussian(key, capacity, r_prime)
         else:
             raise ValueError(f"unknown sketch_type {sketch_type!r}")
-        self._bind(kernel, int(r), sketch,
-                   jnp.zeros((capacity, r_prime), jnp.float32),
-                   jnp.zeros((capacity,), jnp.float32), 0, None,
+        self._bind(kernel, int(r), sketch, None, None, 0, None,
                    block=block, truncate_basis=truncate_basis,
                    fwht_fn=fwht_fn, policy=policy,
                    kernel_statics=kernel_statics)
@@ -141,11 +140,10 @@ class SketchAccumulator:
     def _bind(self, kernel, r, sketch, W, row_norms2, n_applied, X, *,
               block, truncate_basis, fwht_fn, policy=None,
               kernel_statics=None) -> None:
+        """W / row_norms2 None: the empty state."""
         self.kernel = kernel
         self.r = int(r)
         self.sketch = sketch
-        self.W = W
-        self.row_norms2 = row_norms2
         self.n_applied = int(n_applied)
         self._X = X
         self.block = int(block)
@@ -167,6 +165,19 @@ class SketchAccumulator:
                 "for the Pallas fit_sketch kernel — fit through "
                 "KernelKMeans (which passes them from the spec) or give "
                 "SketchAccumulator kernel_statics=")
+        # The single-host fused path keeps the state in the in-place
+        # kernel's layout and the pass's sketch rows prepared for it.
+        self._inplace = self._fit_fused and policy.mesh is None
+        self._rows_m, self._rows = -1, None
+        if self._inplace:
+            from repro.kernels.fit_sketch.ops import to_kernel_state
+            W, row_norms2 = to_kernel_state(W, row_norms2, self.capacity,
+                                            self.r_prime)
+        elif W is None:
+            W = jnp.zeros((self.capacity, self.r_prime), jnp.float32)
+            row_norms2 = jnp.zeros((self.capacity,), jnp.float32)
+        self.W = W
+        self.row_norms2 = row_norms2
         if X is not None:
             self._ensure_engine(int(X.shape[0]))
 
@@ -261,7 +272,8 @@ class SketchAccumulator:
 
     @property
     def r_prime(self) -> int:
-        return int(self.W.shape[1])
+        return (self.sketch.r_prime if isinstance(self.sketch, SRHT)
+                else int(self.sketch.omega.shape[1]))
 
     @property
     def n_added(self) -> int:
@@ -376,20 +388,33 @@ class SketchAccumulator:
         return W, row_norms2
 
     def _apply_fused(self, W, row_norms2, q, b):
-        """Single-host block update through the fused fit_sketch Pallas
-        kernel: gram-stripe -> sketch-accumulate in one pass with the
-        accumulator VMEM-resident. Materializes the Omega rows of every
-        column added so far (the price of trading the FWHT for an MXU
-        contraction; the distributed engine shards that slab instead)."""
+        """Single-host block update through the in-place fit_sketch
+        Pallas kernel: gram-stripe -> sketch-accumulate in one pass, W
+        and row_norms2 (the kernel's layout) updated in place and
+        donated. The Omega rows of every column added so far are
+        materialized once per pass (fit.prepare), when the column count
+        has changed since the last block (the price of trading the FWHT
+        for an MXU contraction; the distributed engine shards that slab
+        instead)."""
         kind, gamma, degree = self.kernel_statics
-        if isinstance(self.sketch, SRHT):
-            aux, rows = self.sketch.signs, self.sketch.rows
-        else:
-            aux, rows = self.sketch.omega, None
+        m = self.n_added
+        if self._rows_m != m:
+            from repro.kernels.fit_sketch.ops import padded_shapes
+            if isinstance(self.sketch, SRHT):
+                aux, rows = self.sketch.signs, self.sketch.rows
+            else:
+                aux, rows = self.sketch.omega, None
+            self._rows = None           # free the last pass's rows first
+            with span("fit.prepare", m=m,
+                      m_pad=padded_shapes(m, 1, 1)[1]):
+                self._rows = _prepared_rows(
+                    aux, rows, m=m,
+                    n_pad=int(getattr(self.sketch, "n_pad", 0)))
+            self._rows_m = m
         return _fused_block_update(
-            self._X, W, row_norms2, aux, rows, jnp.asarray(q, jnp.int32),
-            b=int(b), n_pad=int(getattr(self.sketch, "n_pad", 0)),
-            kind=kind, gamma=float(gamma), degree=int(degree),
+            self._X, W, row_norms2, self._rows, None,
+            jnp.asarray(q, jnp.int32), b=int(b), kind=kind,
+            gamma=float(gamma), degree=int(degree),
             interpret=self._fit_interpret)
 
     def _effective_state(self):
@@ -402,15 +427,28 @@ class SketchAccumulator:
         (the sketch is the ONLY thing small enough to be worth
         gathering — the paper's point)."""
         tail = self.n_added - self.n_applied
-        if tail == 0:
-            W, rn, n_eff = self.W, self.row_norms2, self.n_applied
-        else:
-            W, rn = self._apply(self.W, self.row_norms2, self.n_applied,
-                                tail)
+        W, rn, n_eff = self.W, self.row_norms2, self.n_applied
+        if tail:
+            if self._inplace:           # the tail's update donates these
+                W, rn = jnp.copy(W), jnp.copy(rn)
+            W, rn = self._apply(W, rn, self.n_applied, tail)
+            # The tail ends the pass: the next block folds in more
+            # columns, for which the sketch rows are prepared anew.
+            self._rows_m, self._rows = -1, None
             n_eff = self.n_added
+        return (*self._logical(W, rn), n_eff)
+
+    def _logical(self, W, rn):
+        """(W (capacity, r'), row_norms2 (capacity,)) from the state as
+        this accumulator holds it: gathered from the mesh, or read out
+        of the in-place kernel's layout (new buffers, never the ones the
+        next block update donates)."""
         if self._engine is not None:
-            W, rn = self._engine.gather(W), self._engine.gather(rn)
-        return W, rn, n_eff
+            return self._engine.gather(W), self._engine.gather(rn)
+        if self._inplace:
+            from repro.kernels.fit_sketch.ops import from_kernel_state
+            return from_kernel_state(W, rn, self.capacity, self.r_prime)
+        return W, rn
 
     # -- eigendecomposition ----------------------------------------------
 
@@ -464,12 +502,8 @@ class SketchAccumulator:
                   "sketch_rows": self.sketch.rows}
         else:
             st = {"sketch_omega": self.sketch.omega}
-        if self._engine is not None:
-            st["stream_w"] = self._engine.gather(self.W)
-            st["stream_row_norms2"] = self._engine.gather(self.row_norms2)
-        else:
-            st["stream_w"] = self.W
-            st["stream_row_norms2"] = self.row_norms2
+        st["stream_w"], st["stream_row_norms2"] = self._logical(
+            self.W, self.row_norms2)
         st["stream_counts"] = jnp.array([self.n_applied, self.capacity],
                                         jnp.int32)
         return st

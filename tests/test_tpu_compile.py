@@ -15,6 +15,7 @@ the module is imported: one process at a time may load the TPU library,
 and every pytest worker imports every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.extend_embed.ops import extend_embed_pallas
-from repro.kernels.fit_sketch.ops import fit_sketch_pallas
+from repro.kernels.fit_sketch.ops import fit_sketch_pallas, padded_shapes
 from repro.kernels.fwht.ops import fwht_pallas
 from repro.kernels.gram.ops import gram_stripe_pallas
 from repro.kernels.kmeans_assign.ops import assign_pallas
@@ -75,13 +76,21 @@ def _fit_sketch_border(p, m, rp, b=512):
              ((), jnp.int32)])
 
 
-def _fit_block_update(p, m, rp, b, n_pad):
-    """One fit block of width b over m columns, SRHT padded to n_pad."""
-    return (lambda X, W, rn, signs, rows, q: _fused_block_update(
-                X, W, rn, signs, rows, q, b=b, n_pad=n_pad, kind="rbf",
-                gamma=GAMMA, degree=2, interpret=False),
-            [_f32(p, m), _f32(m, rp), _f32(m), _f32(n_pad),
-             ((rp,), jnp.int32), ((), jnp.int32)])
+def _block_shapes(p, m, rp):
+    """(X, W, row norms, prepared Omega rows) of a pass over m columns,
+    the state sized to capacity m, in the in-place kernel's layout."""
+    _, m_pad, _, rp_pad = padded_shapes(m, 1, rp)
+    return [_f32(p, m), _f32(m_pad, rp_pad), _f32(8, m_pad),
+            _f32(m_pad, rp_pad)]
+
+
+def _fit_block_update(p, m, rp, b):
+    """One fit block of width b over m columns through the in-place
+    kernel, the sketch rows prepared for the pass."""
+    return (lambda X, W, rn, rows_k, q: _fused_block_update(
+                X, W, rn, rows_k, None, q, b=b, kind="rbf", gamma=GAMMA,
+                degree=2, interpret=False),
+            [*_block_shapes(p, m, rp), ((), jnp.int32)])
 
 
 # name -> (function of arrays, argument (shape, dtype)s)
@@ -102,12 +111,12 @@ CASES = {
         [_f32(P, N), _f32(N, 20), _f32(P, 512), _f32(512, 20),
          _f32(8, N)]),
     "fit_sketch_border": _fit_sketch_border(P, N, 20),
-    "fit_block_update": _fit_block_update(P, N, 20, 512, 1 << 17),
+    "fit_block_update": _fit_block_update(P, N, 20, 512),
     "fit_sketch_border_covtype_full": _fit_sketch_border(CP, CN, CR),
     "fit_block_update_covtype_full_b512": _fit_block_update(
-        CP, CN, CR, 512, 1 << 20),
+        CP, CN, CR, 512),
     "fit_block_update_covtype_full_b404": _fit_block_update(
-        CP, CN, CR, CN % 512, 1 << 20),
+        CP, CN, CR, CN % 512),
     "gram": (
         lambda X, Xb: gram_stripe_pallas(X, Xb, kind="rbf", gamma=GAMMA,
                                          interpret=False),
@@ -126,3 +135,93 @@ def test_compiles_for_v5e(name, one_chip, no_compile_cache):
               for shape, dtype in args]
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the fit's per-block update: nothing O(m) outside the kernel ----------
+
+# Opcodes that may hold an axis of >= m rows: the kernel itself, the
+# state it updates in place, and views of it.
+_IN_PLACE = {"parameter", "get-tuple-element", "tuple", "bitcast", "call",
+             "dynamic-update-slice"}
+
+
+def _computations(hlo):
+    """{computation name: [(instruction name, result dims, opcode, line)]}
+    of an HLO module's text."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) (?:\(.*\) -> .* )?\{$",
+                        line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+            continue
+        if cur is None or " = " not in line:
+            continue
+        name, rhs = line.strip().split(" = ", 1)
+        op = re.search(r"(?<![\w])([a-z][a-z0-9\-]*)\(", rhs)
+        dims = [int(d) for g in re.findall(r"\[([\d,]+)\]",
+                                           rhs[:op.start()])
+                for d in g.split(",")]
+        cur.append((name.replace("ROOT ", "").lstrip("%"), dims,
+                    op.group(1), line))
+    return comps
+
+
+def _o_m_outside_kernel(hlo, m):
+    """Instructions with a result axis of >= m rows that are neither the
+    fit_sketch kernel, an in-place update of the state, nor a view."""
+    comps = _computations(hlo)
+
+    def root(comp):
+        return next(i for i in reversed(comps[comp]) if "ROOT" in i[3])
+
+    bad = []
+    for insts in comps.values():
+        for name, dims, op, line in insts:
+            if max(dims, default=0) < m or op in _IN_PLACE:
+                continue
+            if op == "custom-call" and "tpu_custom_call" in line:
+                continue
+            called = re.search(r"calls=%?([\w.\-]+)", line)
+            if (op == "fusion" and called
+                    and root(called.group(1))[2] == "dynamic-update-slice"):
+                continue
+            bad.append(f"{name}: {op} {dims}")
+    return bad
+
+
+def _block_update_on(one_chip, p, m, rp, b):
+    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+              for shape, dtype in [*_block_shapes(p, m, rp),
+                                   ((), jnp.int32)]]
+    X, W, rn, rows_k, q = shapes
+    return _fused_block_update.lower(X, W, rn, rows_k, None, q, b=b,
+                                     kind="rbf", gamma=GAMMA, degree=2,
+                                     interpret=False)
+
+
+def test_a_block_update_touches_no_O_m_rows_outside_the_kernel(
+        one_chip, no_compile_cache):
+    """The per-block program at m = 700, b = 128, as lowered for a v5e:
+    every array of 700 or more rows is the kernel's, the state updated in
+    place (the O(b) new rows written at q) or a view; the state is
+    donated and aliased to the outputs."""
+    lowered = _block_update_on(one_chip, CP, 700, CR, 128)
+    hlo = lowered.as_text(dialect="hlo")
+    assert _o_m_outside_kernel(hlo, 700) == []
+    assert ("input_output_alias={ {0}: (1, {}, may-alias), "
+            "{1}: (2, {}, may-alias) }") in hlo
+    assert "output_to_operand_aliasing={{1}: (5, {}), {2}: (6, {})}" in hlo
+
+
+def test_the_compiled_block_update_at_covtype_full_adds_no_copy(
+        one_chip, no_compile_cache):
+    """Compiled for a v5e at the published Covertype shape (581,012 rows,
+    b = 512), XLA adds no copy, pad or transpose of the O(m) arrays
+    around the kernel, and the donated state stays aliased."""
+    compiled = _block_update_on(one_chip, CP, CN, CR, 512).compile()
+    hlo = compiled.as_text()
+    assert _o_m_outside_kernel(hlo, CN) == []
+    assert ("input_output_alias={ {0}: (1, {}, may-alias), "
+            "{1}: (2, {}, may-alias) }") in hlo
+    assert re.search(r"%fit_sketch_inplace[\w.]* = .* custom-call\(", hlo)
