@@ -1,23 +1,17 @@
 """Benchmark harness: one function per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV rows. Wall times are CPU-host times
-(the TPU perf story lives in the dry-run roofline, benchmarks/roofline.py);
-the derived column carries the paper-comparable metric.
+Prints ``name,us_per_call,derived`` CSV rows. The derived column carries
+the paper-comparable metric; the wall times are the host's and say nothing
+about the chip, which `bench/run.py` (BENCHMARK.json) measures.
 
   table1    Table 1: accuracy + approx error, ours vs exact vs Nystrom vs
             plain K-means (blob+ring primary geometry, rings secondary)
   fig3      Fig. 3: error/accuracy vs sampled columns m (seg-proxy data)
   theorem1  Thm. 1 bound tightness over random PSD matrices
   memory    memory footprint: ours O(r'n) vs Nystrom O(mn) at matched error
-  kernels   Pallas kernel microbench (interpret mode) vs jnp oracle
-  backends  the estimator-API sweep: every --backends entry fitted through
-            repro.api.KernelKMeans on the same data (accuracy, approx
-            error, fit memory model)
 
-Select sections with --sections (comma list; default: all); --backends
-restricts the estimator sweep's backend list. The paper-table sections
-run through the unified estimator API (`repro.api.KernelKMeans`) — the
-historical free functions are deprecation shims over the same code paths.
+Select sections with --sections (comma list; default: all). The one-pass
+fits run through the estimator API (`repro.api.KernelKMeans`).
 """
 from __future__ import annotations
 
@@ -27,17 +21,6 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-
-def _timeit(fn, n=3):
-    fn()  # compile
-    t0 = time.perf_counter()
-    out = None
-    for _ in range(n):
-        out = fn()
-    if out is not None:
-        jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / n * 1e6
 
 
 def _row(name, us, derived):
@@ -176,74 +159,14 @@ def memory():
          f"bytes={ny_bytes};m={m};ratio={ny_bytes/ours_bytes:.1f}x")
 
 
-def kernels():
-    from repro.kernels import fwht_pallas, gram_stripe_pallas, assign_pallas
-    from repro.kernels.fwht.ref import fwht_ref
-    from repro.kernels.gram.ref import gram_stripe_ref
-    from repro.kernels.kmeans_assign.ref import assign_ref
-
-    x = jax.random.normal(jax.random.PRNGKey(0), (4096, 16))
-    us_p = _timeit(lambda: fwht_pallas(x, interpret=True))
-    us_r = _timeit(lambda: fwht_ref(x))
-    err = float(jnp.max(jnp.abs(fwht_pallas(x, interpret=True) -
-                                fwht_ref(x))))
-    _row("kernels.fwht_4096x16", us_p, f"ref_us={us_r:.0f};maxerr={err:.1e}")
-
-    X = jax.random.normal(jax.random.PRNGKey(1), (19, 2048))
-    Xb = X[:, :256]
-    us_p = _timeit(lambda: gram_stripe_pallas(X, Xb, interpret=True))
-    err = float(jnp.max(jnp.abs(gram_stripe_pallas(X, Xb, interpret=True) -
-                                gram_stripe_ref(X, Xb))))
-    _row("kernels.gram_2048x256", us_p, f"maxerr={err:.1e}")
-
-    Y = jax.random.normal(jax.random.PRNGKey(2), (4096, 16))
-    C = jax.random.normal(jax.random.PRNGKey(3), (8, 16))
-    us_p = _timeit(lambda: assign_pallas(Y, C, interpret=True))
-    l1, _ = assign_pallas(Y, C, interpret=True)
-    l2, _ = assign_ref(Y, C)
-    _row("kernels.assign_4096x16x8", us_p,
-         f"label_agreement={float(jnp.mean(l1 == l2)):.4f}")
-
-
-def backends(names=None):
-    """Estimator-API sweep: every backend on the same data + kernel.
-
-    The unified-front-door version of Table 1's comparison: accuracy,
-    approximation error, and the fit memory model per registered backend,
-    all through repro.api.KernelKMeans.
-    """
-    from repro.api import KernelKMeans, available_backends, fit_memory_bytes
-    from repro.core import clustering_accuracy, kernel_approx_error_streaming
-    from repro.data import blob_ring
-
-    X, labels = blob_ring(jax.random.PRNGKey(0), 4000)
-    n = X.shape[1]
-    for name in (names or available_backends()):
-        est = KernelKMeans(k=2, r=2, kernel="polynomial",
-                           kernel_params=_POLY, backend=name)
-        t0 = time.perf_counter()
-        est.fit(X, key=jax.random.PRNGKey(7))
-        us = (time.perf_counter() - t0) * 1e6
-        err = kernel_approx_error_streaming(est.model_.kernel_fn(), X,
-                                            est.embedding_)
-        acc = clustering_accuracy(labels, est.labels_, 2)
-        mem = fit_memory_bytes(name, n, 2, **est.backend_params)
-        _row(f"backends.{name}", us,
-             f"err={err:.2f};acc={acc:.2f};fit_bytes={mem};"
-             f"n_ref={est.model_.n_ref}")
-
-
 _SECTIONS = {"table1": table1, "fig3": fig3, "theorem1": theorem1,
-             "memory": memory, "kernels": kernels, "backends": backends}
+             "memory": memory}
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--sections", default=",".join(_SECTIONS),
                     help=f"comma list of {sorted(_SECTIONS)}")
-    ap.add_argument("--backends", default=None,
-                    help="comma list restricting the estimator sweep "
-                         "(default: every registered backend)")
     args = ap.parse_args()
     sections = [s.strip() for s in args.sections.split(",") if s.strip()]
     unknown = set(sections) - set(_SECTIONS)
@@ -252,10 +175,7 @@ def main() -> None:
                  f"have {sorted(_SECTIONS)}")
     print("name,us_per_call,derived")
     for name in sections:
-        if name == "backends" and args.backends:
-            backends([b.strip() for b in args.backends.split(",")])
-        else:
-            _SECTIONS[name]()
+        _SECTIONS[name]()
 
 
 if __name__ == "__main__":

@@ -5,11 +5,11 @@ carry a non-empty `reason`, and matches are as narrow as the entry
 makes them:
 
     [[suppress]]
-    rule   = "J001"                      # required: exact rule id
-    path   = "src/repro/serve/bench.py"  # required: repo-relative path
-    symbol = "benchmark_backends"        # optional: enclosing qualname
-    reason = "same key reused on purpose: every backend must see the "
-             "same draw so the accuracy column compares like for like"
+    rule   = "J001"                         # required: exact rule id
+    path   = "src/repro/data/synthetic.py"  # required: repo-relative path
+    symbol = "blob_ring"                    # optional: enclosing qualname
+    reason = "same key reused on purpose: the blob and the ring must "
+             "share one draw of radii"
 
 Omitting `symbol` suppresses the rule for the whole file (use
 sparingly). Line numbers are deliberately NOT part of the match — they

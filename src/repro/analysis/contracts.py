@@ -1,9 +1,9 @@
 """Kernel memory-contract verifier (rules C001-C003).
 
 The kernel packages declare closed-form byte models (`memory_contract`
-in each ops.py) that serve/bench.py reports as the paper's memory-
-frugality numbers. Nothing about a closed form keeps it honest, so this
-pass derives the SAME quantities from the kernels' actual BlockSpecs
+in each ops.py), which the benchmark's byte models copy
+(bench/lib/costs.py). Nothing about a closed form keeps it honest, so
+this pass derives the SAME quantities from the kernels' actual BlockSpecs
 and fails on divergence:
 
 * Every registered package's `op` is invoked (through its own public
@@ -19,7 +19,7 @@ and fails on divergence:
   contract's budget at every registered parity case.
 
 Derivation is per parity case, so a drifted tile size, a forgotten
-padding change, or a new output that bench.py's model missed all
+padding change, or a new output that the declared model missed all
 surface as C001 the moment they land.
 """
 from __future__ import annotations

@@ -2,7 +2,7 @@
 
 PartitionSpec policy for the LM-workload side of the repo (models/,
 train/, launch/dryrun); the clustering pipeline's sharding lives in
-distributed/cluster.py (training) and serve/extend.py::ShardedExtender
+distributed/fit.py (training) and serve/extend.py::ShardedExtender
 (the mesh-sharded extension matmul, ROADMAP "Serve subsystem").
 
 Scheme:

@@ -15,14 +15,12 @@ in one process. This package is the tier above it:
     rollout.py     RolloutManager — canary-then-promote version rollouts
                    gated on post-swap p95, rollback on breach
     tier.py        Fleet — the front door composing all of the above
-    bench.py       benchmark_fleet — the gated soak bench
 
 Workers communicate ONLY through the shared VersionStore on disk — no
-in-memory channel — so the in-process topology used by tests and benches
+in-memory channel — so the in-process topology used by the tests
 is honestly the multi-process one.
 """
 from repro.fleet.admission import AdmissionController, ShedError
-from repro.fleet.bench import benchmark_fleet
 from repro.fleet.controller import AdaptiveWaitController
 from repro.fleet.rollout import RolloutManager, RolloutReport
 from repro.fleet.router import Router
@@ -38,5 +36,4 @@ __all__ = [
     "RolloutReport",
     "Router",
     "ShedError",
-    "benchmark_fleet",
 ]

@@ -11,8 +11,8 @@ queue-depth cap   each worker may hold at most `max_queue_depth` query
                   "queue-full"). The cap IS the latency bound: admitted
                   work never waits behind more than max_queue_depth
                   columns of compute, so admitted p99 stays within the
-                  SLO by construction — the property the fleet soak
-                  bench gates.
+                  SLO by construction (tests/test_fleet.py checks it
+                  under a flood).
 
 SLO breaker       `update(p99_ms)` feeds the tier-level p99 (merged
                   LatencyStats) back in; while it breaches `slo_ms` the
@@ -24,8 +24,8 @@ SLO breaker       `update(p99_ms)` feeds the tier-level p99 (merged
 
 Shedding is typed (`ShedError`), never silent: the caller sees which
 worker, what depth, which reason — a load balancer retries elsewhere, a
-client backs off. Counters (admitted/shed per reason) are the bench's
-shed-rate read-out, lock-guarded because submits race the breaker update.
+client backs off. Counters (admitted/shed per reason) give the shed
+rate, lock-guarded because submits race the breaker update.
 """
 from __future__ import annotations
 
@@ -145,7 +145,7 @@ class AdmissionController:
         return shed / offered if offered else 0.0
 
     def summary(self) -> Dict:
-        """JSON-ready counters (the bench's overload section)."""
+        """JSON-ready counters."""
         with self._lock:
             shed = dict(self._shed)
             return {

@@ -76,8 +76,7 @@ class AdaptiveWaitController:
         """One control period for one worker; returns the adjustments.
 
         Each row: {worker, bucket, requests, p95_ms, wait_before_ms,
-        wait_after_ms, action} with action in decrease/increase/hold —
-        the rollout-timeline-style trace the fleet bench records.
+        wait_after_ms, action} with action in decrease/increase/hold.
         """
         sched = worker.scheduler()
         out: List[Dict] = []

@@ -18,7 +18,7 @@ a fleet needs an ORDER. The state machine here is the standard one:
 
 Every transition is a warm `FleetWorker.swap_to` — in-flight requests
 drain into the model that accepted them, so a rollout (or a rollback)
-strands zero futures; the soak bench re-asserts it. Version pins make
+strands zero futures. Version pins make
 the rollback always possible: the canary's OLD version stays pinned by
 every not-yet-promoted worker, so no GC between canary and verdict can
 delete the escape hatch.
@@ -41,7 +41,7 @@ STATES = ("idle", "canary", "probing", "promoting", "done", "rolled-back")
 
 @dataclasses.dataclass
 class RolloutReport:
-    """What one rollout did (the bench's rollout-timeline section)."""
+    """What one rollout did: states, swaps and the canary's probe."""
     version: int                      # target version
     old_versions: Dict[str, int]      # worker_id -> version before
     canary_id: str

@@ -15,7 +15,7 @@ ShedError) -> worker enqueue; `control()` is one control-loop period:
 poll every worker's deadline, merge per-worker LatencyStats into the
 tier summary, feed tier p99 to the admission breaker and the per-bucket
 breakdowns to the wait controller. The loop is cooperative (the caller
-— a bench, a CLI, an event loop — owns the cadence), exactly like
+— a test, a server loop, an event loop — owns the cadence), exactly like
 AsyncBatcher.poll(): deterministic under test, pump-threaded in a real
 deployment by calling start() on each worker's scheduler.
 
@@ -115,8 +115,7 @@ class Fleet:
         Merges per-worker LatencyStats into the tier summary, feeds the
         tier p99 to the admission breaker and the per-bucket breakdowns
         to the wait controller. Returns {"completed", "p99_ms",
-        "breaker_open", "wait_adjustments"} — the soak bench's
-        control-loop trace."""
+        "breaker_open", "wait_adjustments"}."""
         completed = self.poll()
         stats = self.latency()
         p99 = stats.total.percentile(99.0)

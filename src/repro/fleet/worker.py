@@ -155,7 +155,7 @@ class FleetWorker:
     # -- monitoring ------------------------------------------------------
 
     def stats(self) -> Dict:
-        """One JSON-ready health row (the fleet bench's per-worker dump)."""
+        """One JSON-ready health row for this worker."""
         lat = self.latency
         return {
             "worker_id": self.worker_id,

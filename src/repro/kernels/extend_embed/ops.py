@@ -31,8 +31,8 @@ def padded_shapes(n: int, r: int, w: int, row_tile: int = 256
     """(row_tile, n_pad, r_pad, w_pad) the kernel actually runs at.
 
     The single source of truth for the tiling: extend_embed_pallas pads
-    with exactly these values, and serve/bench.py derives the fused
-    engine's HBM byte count from them (each padded operand crosses HBM
+    with exactly these values, and `memory_contract` counts the fused
+    engine's HBM bytes from them (each padded operand crosses HBM
     once — that IS the kernel's memory contract).
     """
     row_tile = min(row_tile, max(128, 1 << (n - 1).bit_length()))
@@ -48,9 +48,10 @@ def memory_contract(p: int, n: int, r: int, w: int, row_tile: int = 256
 
     X and P stream over the row-tile grid (each padded element crosses
     HBM once); the query block Xb and the (r, w) output stay
-    VMEM-resident across the whole sweep and cross once each. These are
-    the bytes serve/bench.py reports, cross-checked against the
-    BlockSpecs by `repro.analysis` (rule C001).
+    VMEM-resident across the whole sweep and cross once each.
+    `repro.analysis` cross-checks these bytes against the BlockSpecs
+    (rule C001), and the benchmark's byte model (bench/lib/costs.py)
+    is held equal to them by bench/tests/test_costs.py.
     """
     row_tile, n_pad, r_pad, w_pad = padded_shapes(n, r, w, row_tile)
     hbm = 4.0 * (p * n_pad             # X (p, n_pad) streamed

@@ -45,10 +45,9 @@ def padded_shapes(m: int, b: int, rp: int, row_tile: int = 256
     """(row_tile, m_pad, b_pad, rp_pad) the kernel actually runs at.
 
     The single source of truth for the tiling: fit_sketch_pallas pads
-    with exactly these values, and the "fit_scaling" bench section
-    (serve/bench.py) derives the fused fit engine's HBM byte count from
-    them — each padded operand crosses HBM once, that IS the kernel's
-    memory contract.
+    with exactly these values, and `memory_contract` counts the fused
+    fit engine's HBM bytes from them — each padded operand crosses HBM
+    once, that IS the kernel's memory contract.
     """
     row_tile = min(row_tile, max(128, 1 << (m - 1).bit_length()))
     m_pad = -(-m // row_tile) * row_tile
@@ -74,9 +73,10 @@ def memory_contract(p: int, m: int, b: int, rp: int, row_tile: int = 256
     Every operand block crosses HBM exactly once per distinct grid
     coordinate (moving operands stream, constant-index operands stay
     VMEM-resident), so the f32 traffic is the sum of the padded operand
-    footprints. serve/bench.py reports THESE numbers and
-    `repro.analysis` cross-checks them against the kernel's BlockSpecs
-    at every registered parity case (rule C001).
+    footprints. `repro.analysis` cross-checks THESE numbers against the
+    kernel's BlockSpecs at every registered parity case (rule C001), and
+    bench/tests/test_costs.py holds the benchmark's byte model equal
+    to them.
 
     This is the full-sweep upper bound (no `border`). A call bounded to
     `border` rows moves X, Omega, V, delta and the row norms for its

@@ -54,11 +54,12 @@ class KernelContract(NamedTuple):
     """One kernel package's declared memory-contract model.
 
     declared: (case) -> dict with at least "hbm_bytes": the closed-form
-           byte model for one parity case — the number serve/bench.py
-           reports. `repro.analysis` cross-checks it against the HBM
-           traffic derived from the kernel's actual BlockSpecs at every
-           registered case, so the model cannot silently drift from the
-           kernel (rule C001).
+           byte model for one parity case (the benchmark's byte model,
+           bench/lib/costs.py, is held equal to it by
+           bench/tests/test_costs.py). `repro.analysis` cross-checks it
+           against the HBM traffic derived from the kernel's actual
+           BlockSpecs at every registered case, so the model cannot
+           silently drift from the kernel (rule C001).
     vmem_budget: per-grid-step VMEM residency ceiling in bytes the
            kernel must stay under at every registered case (rule C002).
     """

@@ -36,20 +36,12 @@ WHICHEVER backend produced it — into a deployable service:
                 scheduler — SwapReport measures the flip)
   versions.py   versioned artifact store: <root>/v_<N>/ on the atomic
                 checkpoint commit; publish / pinned loads / keep-last-K GC
-  bench.py      sync/async/sharded/swap benchmarks -> BENCH_serve.json
 
-CLI: `python -m repro.launch.serve_cluster --smoke` round-trips
-fit -> save -> load -> query; `--bench async` reports latency percentiles.
 Docs: docs/SERVING.md (serving semantics), docs/ARCHITECTURE.md (layers).
 """
 from repro.serve.artifact import (ClusteringSpec, FittedModel, ModelSpec,
                                   fit_model, load_model, save_model)
 from repro.serve.batcher import MicroBatcher, bucket_size
-from repro.serve.bench import (benchmark_assign, benchmark_async,
-                               benchmark_backends, benchmark_fit_scaling,
-                               benchmark_fused, benchmark_swap,
-                               format_bench, median_benches, run_benches,
-                               write_bench)
 from repro.serve.extend import (Extender, ShardedExtender, assign, embed,
                                 embed_sharded)
 from repro.serve.policy import (ComputePolicy, data_mesh,
@@ -66,9 +58,6 @@ __all__ = [
     "ClusteringSpec", "FittedModel", "ModelSpec", "fit_model",
     "load_model", "save_model",
     "MicroBatcher", "bucket_size",
-    "benchmark_assign", "benchmark_async", "benchmark_backends",
-    "benchmark_fit_scaling", "benchmark_fused", "benchmark_swap",
-    "format_bench", "median_benches", "run_benches", "write_bench",
     "Extender", "ShardedExtender", "assign", "embed", "embed_sharded",
     "ComputePolicy", "data_mesh", "merge_legacy_kwargs",
     "resolve_pallas_path",

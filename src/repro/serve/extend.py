@@ -44,7 +44,7 @@ govern the Pallas kmeans_assign assignment path (`assign_fused=`).
 
 Mesh-sharded path (`ShardedExtender`): the extension matmul
 P kappa(X_train, x) is the serving-time hot loop, and it shards the same
-way the training pass does (distributed/cluster.py): X_train and P both
+way the sharded fit does (distributed/fit.py): X_train and P both
 column-sharded over the mesh's data axis, each device computing its
 n/shards x block stripe of the kernel against the replicated query block
 fused into its (r, block) partial projection, combined by ONE psum of the

@@ -212,8 +212,8 @@ class LatencyStats:
         return self.slo_violations / self.requests if self.requests else 0.0
 
     def summary(self) -> Dict:
-        """JSON-ready summary — the schema BENCH_serve.json's async mode
-        embeds (see docs/SERVING.md, "SLO metrics glossary")."""
+        """JSON-ready summary (see docs/SERVING.md, "SLO metrics
+        glossary")."""
         t, w = self.total, self.queue_wait
         return {
             "requests": self.requests,
@@ -230,8 +230,8 @@ class LatencyStats:
                 "p95": w.percentile(95.0),
                 "p99": w.percentile(99.0),
             },
-            # Per-execution-bucket total latency (string keys: this dict
-            # is JSON-serialized verbatim into BENCH_serve.json).
+            # Per-execution-bucket total latency (string keys, so the
+            # dict serializes to JSON as it is).
             "per_bucket": {
                 str(b): {
                     "requests": h.n,
@@ -248,8 +248,8 @@ class LatencyStats:
         }
 
     def format_table(self) -> str:
-        """Human-readable latency table (printed by serve_cluster --bench
-        and examples/serve_async.py)."""
+        """Human-readable latency table (printed by
+        examples/serve_async.py)."""
         s = self.summary()
         lines = [
             f"{'requests':>14s}: {s['requests']}",

@@ -7,7 +7,7 @@ some front doors and not others, and the fit path had no knobs at all.
 A `ComputePolicy` collapses all of them into one value-compared frozen
 dataclass accepted uniformly by Extender, ShardedExtender, MicroBatcher,
 AsyncBatcher, ModelRegistry (via the recorded front-end kwargs),
-serve_cluster, and — new with the sharded fit — SketchAccumulator /
+and — new with the sharded fit — SketchAccumulator /
 KernelKMeans.fit / KernelKMeans.partial_fit.
 
 Fields (all tri-state: None = auto, True/False = explicit):

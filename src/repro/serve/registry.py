@@ -45,8 +45,7 @@ _MISSING = object()
 
 @dataclasses.dataclass
 class SwapReport:
-    """What a warm hot-swap measured (the "swap" section of
-    BENCH_serve.json serializes this via to_dict()).
+    """What a warm hot-swap measured (`to_dict()` gives it as JSON).
 
     warm_s is paid OFF the serving path (the old row keeps serving while
     the new one compiles); flip_ms is the only window in which neither
@@ -320,7 +319,7 @@ class ModelRegistry:
         return row.scheduler.stop()
 
 
-# Process-wide default registry (what the serve_cluster CLI drives).
+# Process-wide default registry (what the examples drive).
 DEFAULT_REGISTRY = ModelRegistry()
 
 

@@ -145,33 +145,6 @@ def check_sketched_allreduce_pmean():
     print("sketched_allreduce ok")
 
 
-def check_distributed_clustering():
-    """The distributed Alg. 1 matches the single-device pipeline: same
-    kernel approx error regime and high clustering accuracy on blob+ring."""
-    from repro.distributed.cluster import distributed_one_pass_kernel_kmeans
-    from repro.core import (polynomial_kernel, gram_matrix,
-                            exact_eig_from_gram, kernel_approx_error,
-                            clustering_accuracy)
-    from repro.data import blob_ring
-
-    mesh = _mesh((8,), ("data",))
-    n = 1024                                 # power of two (pre-padded)
-    X, labels_true = blob_ring(jax.random.PRNGKey(0), n=n)
-    kern = polynomial_kernel(gamma=0.0, degree=2)
-    Xs = jax.device_put(X, NamedSharding(mesh, P(None, "data")))
-    res = distributed_one_pass_kernel_kmeans(
-        jax.random.PRNGKey(1), kern, Xs, k=2, r=2, mesh=mesh,
-        oversampling=10, block=256)
-    K = gram_matrix(kern, X)
-    err = kernel_approx_error(K, np.asarray(res.Y))
-    err_exact = kernel_approx_error(K, exact_eig_from_gram(K, 2).Y)
-    assert err <= 1.05 * err_exact + 1e-6, (err, err_exact)
-    acc = clustering_accuracy(labels_true, np.asarray(res.labels), 2)
-    assert acc > 0.95, acc
-    print(f"distributed_clustering ok err={err:.3f} "
-          f"(exact {err_exact:.3f}) acc={acc:.3f}")
-
-
 def check_sharded_extend():
     """Serving-side sharded extension (serve.extend.ShardedExtender)
     matches the single-device path to fp32 tolerance, end to end through
@@ -229,7 +202,6 @@ def check_sharded_extend():
 
 
 if __name__ == "__main__":
-    check_distributed_clustering()
     check_sharded_extend()
     check_distributed_fwht()
     check_dfwht_on_2d_mesh()

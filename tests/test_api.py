@@ -26,8 +26,8 @@ from repro.core import (clustering_accuracy, kernel_approx_error,
                         make_kernel)
 from repro.core.kernels_fn import gram_matrix
 from repro.data import gaussian_blobs
-from repro.serve import (ClusteringSpec, ModelRegistry, ModelSpec,
-                         VersionStore, assign, load_model)
+from repro.serve import (ClusteringSpec, MicroBatcher, ModelRegistry,
+                         ModelSpec, VersionStore, assign, load_model)
 from repro.serve.extend import _projection
 
 BACKENDS = ("exact", "nystrom", "onepass-gaussian", "onepass-srht")
@@ -52,6 +52,16 @@ def fits(blobs):
                            block=64)
         out[name] = est.fit(X, key=2)
     return out
+
+
+def _direct_labels(model, ref, Xq):
+    """Nearest-centroid labels of a direct evaluation of the extension
+    y(x) = Sigma^{-1/2} U^T kappa(ref, x), kappa the fits' RBF kernel."""
+    Yq = _projection(model) @ make_kernel("rbf", gamma=1.0)(ref, Xq)
+    d2 = (jnp.sum(Yq.T ** 2, 1)[:, None]
+          + jnp.sum(model.centroids ** 2, 1)[None, :]
+          - 2.0 * Yq.T @ model.centroids.T)
+    return np.asarray(jnp.argmin(d2, axis=1), np.int32)
 
 
 def test_registry_lists_all_four_backends():
@@ -79,17 +89,27 @@ def test_backend_parity_accuracy_and_error(blobs, fits):
             f"{name}: err {err} beats the exact rank-r floor {err_exact}"
 
 
-def test_backend_parity_serving_assignment(blobs, fits):
+@pytest.mark.parametrize("name", BACKENDS)
+def test_backend_parity_serving_assignment(blobs, fits, name):
     """Every backend's fit predicts through the same serving path, and
     held-out assignments agree with the exact backend's up to the label
-    permutation (centroid order is seed/backend dependent)."""
+    permutation (centroid order is seed/backend dependent). The bucketed
+    serving stack assigns exactly as a direct evaluation of the
+    backend's own extension y(x) = Sigma^{-1/2} U^T kappa(ref, x), ref
+    being the training set or the Nystrom landmarks."""
     X, _ = blobs
     Xq = X[:, :60]
-    ref = fits["exact"].predict(Xq)
-    for name, est in fits.items():
-        got = est.predict(Xq)
-        agree = clustering_accuracy(ref, got, 3)
-        assert agree >= 0.95, f"{name}: only {agree:.2f} label agreement"
+    est = fits[name]
+    got = est.predict(Xq)
+    agree = clustering_accuracy(fits["exact"].predict(Xq), got, 3)
+    assert agree >= 0.95, f"{name}: only {agree:.2f} label agreement"
+
+    ref = est.model_.landmarks if name == "nystrom" else X
+    want = _direct_labels(est.model_, ref, Xq)
+    served, _ = MicroBatcher(est.model_, max_bucket=64).assign_batch(Xq)
+    assert np.array_equal(served, want), \
+        f"{name}: served stack != direct extension assignment"
+    assert np.array_equal(np.asarray(got), want), name
 
 
 def test_memory_model_ordering(blobs):
@@ -112,6 +132,7 @@ def test_nystrom_landmark_artifact_smaller_and_exact(blobs, fits):
     assert model.landmarks is not None and model.landmarks.shape == (4, 120)
     assert model.landmark_idx is not None
     assert model.U.shape[0] == model.n_ref == 120
+    assert model.n_ref < X.shape[1]     # serves at landmark height, not n
     Y_ext = est.embed(X)
     rel = (float(jnp.linalg.norm(Y_ext - est.embedding_)) /
            float(jnp.linalg.norm(est.embedding_)))
@@ -171,12 +192,7 @@ def test_nystrom_full_serve_roundtrip(tmp_path, blobs, fits):
     labels_async = np.concatenate([f.result()[0] for f in pre])
 
     # Direct Nystrom embedding: y(x) = Lambda_r^{-1/2} U_r^T k(landmarks, x)
-    P = _projection(served)
-    Yq = P @ make_kernel("rbf", gamma=1.0)(served.landmarks, jnp.asarray(Xq))
-    d2 = (jnp.sum(Yq.T ** 2, 1)[:, None]
-          + jnp.sum(served.centroids ** 2, 1)[None, :]
-          - 2.0 * Yq.T @ served.centroids.T)
-    want = np.asarray(jnp.argmin(d2, axis=1), np.int32)
+    want = _direct_labels(served, served.landmarks, jnp.asarray(Xq))
     assert np.array_equal(labels_async, want), \
         "served stack != direct Nystrom embedding assignment"
 
@@ -317,24 +333,3 @@ def test_make_kernel_rejects_unknown_params():
 def test_kernel_kmeans_validates_kernel_name_early():
     with pytest.raises(ValueError, match="unknown kernel"):
         KernelKMeans(kernel="polynomail")
-
-
-# ---------------------------------------------------------------------------
-# backend sweep bench section
-# ---------------------------------------------------------------------------
-
-def test_benchmark_backends_section(blobs):
-    from repro.serve import benchmark_backends
-    X, labels = blobs
-    bench = benchmark_backends(X, labels, k=3, r=4, kernel="rbf",
-                               kernel_params={"gamma": 1.0}, block=64,
-                               batch_size=32, repeats=1,
-                               backends=("onepass-srht", "nystrom"))
-    per = bench["per_backend"]
-    assert set(per) == {"onepass-srht", "nystrom"}
-    for name, row in per.items():
-        assert row["accuracy"] >= 0.95, name
-        assert row["assignments_per_sec"] > 0
-        assert row["fit_memory_bytes"] > 0
-    # Nystrom's serving height is the landmark count, not n.
-    assert per["nystrom"]["n_ref"] < bench["n"]

@@ -510,12 +510,17 @@ def test_fleet_rollout_and_sync_follow_the_store(store, model, model_b):
     clock = FakeClock()
     fleet = Fleet(store, n_workers=2, clock=clock, rollout_budget_ms=100.0)
     assert fleet.sync() is None           # already at latest
+    r = _requests([16], seed=9)[0]
+    pending = fleet.submit(r)             # still queued when the rollout runs
     v2 = store.publish(model_b)
     rep = fleet.sync()                    # follower mode picks it up
     assert rep is not None and rep.promoted
     assert fleet.stats()["versions"] == {"w0": v2, "w1": v2}
+    # The swap drained the queued request into the version it was sent to.
+    assert pending.done()
+    np.testing.assert_array_equal(pending.result()[0],
+                                  np.asarray(assign(model, r)[0]))
     # Labels prove the new version serves: permuted centroids flip them.
-    r = _requests([16], seed=9)[0]
     fut = fleet.submit(r)
     fleet.flush()
     want_new, _ = assign(model_b, r)

@@ -1,8 +1,7 @@
 """Streaming subsystem (repro.stream): partial_fit/fit bit-parity at the
 re-eig boundary, artifact resume, drift detection, minibatch K-means, the
 int8 artifact codec, and the end-to-end drift -> refit -> publish -> swap
-loop under async traffic. CI's stream-smoke job leans on the same pieces
-via `serve_cluster --smoke --stream`."""
+loop under async traffic."""
 import json
 import pathlib
 
